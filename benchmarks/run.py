@@ -47,6 +47,8 @@ def main() -> None:
     ap.add_argument("--json-dir", type=str, default=".",
                     help="where BENCH_*.json reports are written")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     names = (args.only.split(",") if args.only else list(BENCHES))
     failures = 0
     seen: set[str] = set()      # aliases map to one module; run it once

@@ -456,6 +456,14 @@ class JnpBuilder(Builder):
     def _check_round(self, n_runs: int) -> None:
         """Hook for backends whose candidate table is budget-bounded."""
 
+    def _round(self, state: DeviceBuildState, take: int, *, T: int, P: int,
+               K: int | None):
+        """Launch one fused device round; returns ``(state, scalars)``."""
+        cfg = self.config
+        return _device_round(state, jnp.int32(take), T=T,
+                             cap=cfg.table_cap, min_count=cfg.min_count,
+                             P=P, K=K, counts_fn=self._counts_fn)
+
     def build_grammar(self, lists: Sequence[np.ndarray]) -> RePairResult:
         cfg = self.config
         state, meta = self.init_state(lists)
@@ -470,20 +478,16 @@ class JnpBuilder(Builder):
                 take = min(take, cfg.max_rules - num_rules)
             while num_rules + take > state.rule_l.shape[0]:
                 state = self._grow(state, T)
-            new_state, scalars = _device_round(
-                state, jnp.int32(take), T=T, cap=cfg.table_cap,
-                min_count=cfg.min_count, P=P, K=self._rank_k(),
-                counts_fn=self._counts_fn)
+            new_state, scalars = self._round(state, take, T=T, P=P,
+                                             K=self._rank_k())
             n_chosen, kept_any, n_good, n_runs, n_live = map(
                 int, np.asarray(scalars))
             if (self._rank_k() is not None and n_good > self._rank_k()
                     and n_chosen < min(take, n_good)):
                 # ranked table ran dry mid-greedy: redo this round on the
                 # exact full-length variant (rare; parity-critical)
-                new_state, scalars = _device_round(
-                    state, jnp.int32(take), T=T, cap=cfg.table_cap,
-                    min_count=cfg.min_count, P=P, K=None,
-                    counts_fn=self._counts_fn)
+                new_state, scalars = self._round(state, take, T=T, P=P,
+                                                 K=None)
                 n_chosen, kept_any, n_good, n_runs, n_live = map(
                     int, np.asarray(scalars))
             state = new_state

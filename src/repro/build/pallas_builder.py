@@ -32,18 +32,11 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ..kernels import should_interpret
-from ..kernels.pair_count.pair_count import TILE_N, pair_count_pallas
+from ..kernels import count_launch, should_interpret
+from ..kernels.pair_count.ops import tile_stream
+from ..kernels.pair_count.pair_count import pair_count_pallas
 from .base import BuildConfig
 from .jnp_builder import (BIG, I32, JnpBuilder, _cap_kept, _runs_of_sorted)
-
-
-def _tile(x: jax.Array) -> jax.Array:
-    """(Np,) int32 -> (num_tiles, tn) with zero padding."""
-    Np = x.shape[0]
-    tn = min(TILE_N, Np)
-    pad = -(-Np // tn) * tn - Np
-    return jnp.pad(x.astype(I32), (0, pad)).reshape(-1, tn)
 
 
 def _count_ranked_pallas(packed, pa, pb, vp, *, S, cap, min_count, K,
@@ -70,8 +63,8 @@ def _count_ranked_pallas(packed, pa, pb, vp, *, S, cap, min_count, K,
     ca = jnp.where(on, kk // S, -1)
     cb = jnp.where(on, kk % S, -1)
 
-    counts = pair_count_pallas(ca, cb, _tile(pa), _tile(pb),
-                               _tile(vp.astype(I32)), interpret=interpret)
+    counts = pair_count_pallas(ca, cb, tile_stream(pa), tile_stream(pb),
+                               tile_stream(vp), interpret=interpret)
 
     good = on & (counts >= min_count)
     neg = jnp.where(good, -counts, BIG)
@@ -99,6 +92,10 @@ class PallasBuilder(JnpBuilder):
 
     def _rank_k(self) -> int | None:
         return self._Kp
+
+    def _round(self, state, take, *, T, P, K):
+        count_launch("pair_count", self.interpret)
+        return super()._round(state, take, T=T, P=P, K=K)
 
     def _check_round(self, n_runs: int) -> None:
         if self.config.table_cap == 0 and n_runs > self._Kp:

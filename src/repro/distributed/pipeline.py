@@ -29,7 +29,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -80,8 +79,8 @@ def pipeline_apply(mesh: Mesh, stage_axis: str, n_microbatches: int,
         outs = jax.lax.psum(outs, stage_axis)
         return outs.reshape(B, *x_rep.shape[1:])
 
-    return shard_map(body, mesh=mesh, in_specs=(pspecs, P()),
-                     out_specs=P(), check_rep=False)(params, x)
+    return jax.shard_map(body, mesh=mesh, in_specs=(pspecs, P()),
+                         out_specs=P(), check_vma=False)(params, x)
 
 
 def stack_mlp_params(key, n_layers: int, d: int, dtype=jnp.float32):

@@ -34,8 +34,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.jax_index import (FlatIndex, PagedIndex, as_store_backed,
                               build_flat_index, build_paged_index,
@@ -113,6 +112,11 @@ _STACKED_FIELDS = ("c", "starts", "bucket_offsets", "bck_c_pos", "bck_abs",
                    "firsts", "lasts", "lengths", "kbits")
 
 
+def _stacked_specs(mesh: Mesh) -> dict:
+    return {k: index_partition_spec(k, (1, 1), mesh)
+            for k in _STACKED_FIELDS}
+
+
 @functools.lru_cache(maxsize=None)
 def _sharded_dispatch(mesh: Mesh, axis: str, statics: tuple):
     """One jitted shard_map program per (mesh, static bounds): the index
@@ -121,8 +125,7 @@ def _sharded_dispatch(mesh: Mesh, axis: str, statics: tuple):
     §2.3 no-retrace-on-rebuild rule extends to the sharded path."""
     bounds = dict(statics)
     rep = P(None)
-    specs = {k: index_partition_spec(k, (1, 1), mesh)
-             for k in _STACKED_FIELDS}
+    specs = _stacked_specs(mesh)
 
     def local_next_geq(stk, gram, sof, llid, gids, xs):
         stk = {k: v[0] for k, v in stk.items()}  # this shard's block
@@ -133,10 +136,10 @@ def _sharded_dispatch(mesh: Mesh, axis: str, statics: tuple):
         # assembles the replicated answer
         return jax.lax.pmax(jnp.where(mine, vals, -1), axis)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local_next_geq, mesh=mesh,
         in_specs=(specs, rep, rep, rep, rep, rep),
-        out_specs=rep, check_rep=False))
+        out_specs=rep, check_vma=False))
 
 
 def make_sharded_next_geq(fi: FlatIndex, mesh: Mesh, axis: str = "data"):
@@ -146,11 +149,15 @@ def make_sharded_next_geq(fi: FlatIndex, mesh: Mesh, axis: str = "data"):
     ``distributed.sharding.index_partition_spec``)."""
     num_shards = mesh.shape[axis]
     stacked, shard_of_list, local_lid = shard_flat_index(fi, num_shards)
-    stacked = {k: jnp.asarray(v) for k, v in stacked.items()}
-    grammar = {k: getattr(fi, k)
+    # each device holds only its own shard; everything else replicates
+    specs = _stacked_specs(mesh)
+    stacked = {k: jax.device_put(v, NamedSharding(mesh, specs[k]))
+               for k, v in stacked.items()}
+    rep = NamedSharding(mesh, P())
+    grammar = {k: jax.device_put(getattr(fi, k), rep)
                for k in ("sym_left", "sym_right", "sym_sum", "sym_len")}
-    shard_of_list = jnp.asarray(shard_of_list)
-    local_lid = jnp.asarray(local_lid)
+    shard_of_list = jax.device_put(shard_of_list, rep)
+    local_lid = jax.device_put(local_lid, rep)
     statics = (("num_terminals", fi.num_terminals),
                ("max_depth", fi.max_depth), ("max_scan", fi.max_scan),
                ("universe", fi.universe))
@@ -190,6 +197,10 @@ class DeviceEngine(Engine):
         self._bys_incl = None   # [BY04] prefix table, built on first bys
         self._route_host = None  # routing snapshot, set by _attach_store
         self._starts_np = None
+        #: work sent to the host fallback by design (pairs and k-term
+        #: queries whose shortest list passes ``max_short_len``, whole-list
+        #: decodes past ``_DECODE_CAP``), surfaced by the scheduler
+        self.host_routes = {"pairs": 0, "multi": 0, "decodes": 0}
         if mesh is not None and mesh_axis in mesh.axis_names:
             self._sharded_next_geq = make_sharded_next_geq(
                 self.fi, mesh, mesh_axis)
@@ -413,6 +424,7 @@ class DeviceEngine(Engine):
         so jit entries stay O(log max-length) rather than one per length."""
         n = int(self.lengths[i])
         if n > self._DECODE_CAP:
+            self.host_routes["decodes"] += 1
             return super()._decode_list(i)
         bucket = max(16, 1 << (max(1, n - 1)).bit_length())
         row = self._expand([i], bucket)
@@ -439,6 +451,7 @@ class DeviceEngine(Engine):
                 out[qi] = self.compact(row)
         host = np.flatnonzero(to_host)
         if host.size:                   # outlier route: host svs, one batch
+            self.host_routes["pairs"] += int(host.size)
             host_outs = self.fallback.intersect_pairs(
                 list(zip(shorts[host].tolist(), longs[host].tolist())))
             for qi, o in zip(host, host_outs):
@@ -455,6 +468,7 @@ class DeviceEngine(Engine):
         if not order:
             return np.empty(0, dtype=np.int64)
         if self.lengths[order[0]] > self.max_short_len:
+            self.host_routes["multi"] += 1
             return self.fallback.intersect_multi(idxs)
         cand = self._expand(order[:1], self.max_short_len)  # (1, M)
         for i in order[1:]:
@@ -468,9 +482,9 @@ class DeviceEngine(Engine):
         """Cut the score directory at THIS engine's page boundaries by
         default: a paged engine scores by the pages its probe kernels DMA
         by.  (The windowed decode itself is geometry-agnostic — an
-        explicit ``score_page_size`` override wins; only the fused Pallas
-        page-score kernel requires real alignment, and it falls back to
-        this path when the directory is cut differently.)"""
+        explicit ``score_page_size`` override wins; the fused Pallas
+        page-score kernel needs directory pages that divide its stream
+        pages and refuses any other cut.)"""
         if self.score_page_size is not None:
             return int(self.score_page_size)
         pi = getattr(self, "pi", None)

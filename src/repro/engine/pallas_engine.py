@@ -70,31 +70,40 @@ class PallasEngine(DeviceEngine):
         m = max(1, int(self.score_index.max_page_elems))
         return max(128, 1 << (m - 1).bit_length())
 
+    def _score_page_size(self) -> int:
+        """The fused decode addresses a score entry by the stream page
+        that holds it, so directory pages must tile this engine's pages
+        (the launcher's fine 128-symbol directory does); any other cut is
+        refused here rather than served by a different decode."""
+        sp = super()._score_page_size()
+        if int(self.pi.page_size) % sp:
+            raise ValueError(
+                f"score directory page size {sp} does not divide the "
+                f"pallas engine's stream page size {self.pi.page_size}")
+        return sp
+
     def decode_page_batch(self, entries) -> np.ndarray:
         """Fused decode+score device path: page entries decode in one
         grid-blocked ``page_score`` pallas_call (one stream page DMA'd
         per entry — the block the pruning decision skipped never moves);
         the membership probes that score the fresh candidates then ride
         the fused ``list_intersect`` kernel, and the float32 reduction
-        runs on device.  Requires the score directory to be cut at this
-        engine's page boundaries; a foreign geometry falls back to the
-        windowed jnp decode (which reads the flat stream)."""
-        si = self.score_index
-        if (self.resident is not None
-                or int(si.page_size) != int(self.pi.page_size)):
-            # out of core the fused kernel's full-stream operand pack does
-            # not exist; the windowed jnp decode reads the resident pool
+        runs on device.  Out of core the full-stream operand pack does not
+        exist, and the windowed jnp decode reads the resident pool."""
+        if self.resident is not None:
             return super().decode_page_batch(entries)
+        si = self.score_index
         if self._score_pack is None:
             self._score_pack = PS.pad_score_operands(self.pi)
         tables, statics = self._score_pack
         e = np.asarray(entries, np.int64).ravel()
-        pages = si.pg_page[e].astype(np.int64)
-        slo = si.pg_sym_lo[e].astype(np.int64) - pages * int(si.page_size)
+        lo = si.pg_sym_lo[e].astype(np.int64)
+        pages = lo // int(self.pi.page_size)
         return PS.page_decode(
-            tables, statics, pages, slo, si.pg_sym_hi[e] - si.pg_sym_lo[e],
-            si.pg_base[e], si.pg_head[e], si.pg_count[e],
-            b_pad=self.page_elem_bucket(), interpret=self.interpret)
+            tables, statics, pages, lo - pages * int(self.pi.page_size),
+            si.pg_sym_hi[e] - si.pg_sym_lo[e], si.pg_base[e],
+            si.pg_head[e], si.pg_count[e], b_pad=self.page_elem_bucket(),
+            interpret=self.interpret)
 
     def _next_geq_dev(self, list_ids, xs) -> np.ndarray:
         return K.next_geq_paged(self._tables, self._host,

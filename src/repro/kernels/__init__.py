@@ -38,11 +38,28 @@ pallas_call + BlockSpec, ops.py jit wrapper, ref.py oracle):
                         ``repro.build.PallasBuilder`` and is checked
                         bit-exactly against the host pair counter.
 
-All validated on CPU with interpret=True against their refs; BlockSpecs are
-written for TPU v5e VMEM (tiles are multiples of (8, 128) lanes).
+All are checked on the CPU with interpret=True against their refs.  The
+four served kernels (``list_intersect``, ``page_score``, ``ef_next_geq``,
+``pair_count``) are also compiled for TPU v5e at bring-up shapes by
+``tests/test_tpu_compile.py``: every block's last two dims are (8, 128)
+multiples or equal to the array's (paged streams are laid out
+``(num_pages, 1, PAGE)`` with the page dim squeezed), and tables that grow
+with the index are looked up one 128-lane row at a time
+(``kernels.gather``).  The other four have never been compiled for a chip.
 """
 
+import collections
+
 import jax
+
+#: kernel launches in this process by kernel name, counted by the ops
+#: wrappers; a launch in interpret mode counts as ``"<name>[interpret]"``,
+#: so a run can show that each kernel ran, and ran compiled
+LAUNCHES: collections.Counter = collections.Counter()
+
+
+def count_launch(name: str, interpret: bool) -> None:
+    LAUNCHES[f"{name}[interpret]" if interpret else name] += 1
 
 
 def should_interpret() -> bool:

@@ -41,17 +41,11 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..gather import row_gather
+
 TILE_Q = 128
 #: words of the packed low-bits array per grid page
 EF_PAGE = 128
-
-
-def _gather(table: jax.Array, idx: jax.Array, width: int) -> jax.Array:
-    """Exact int32 gather table[idx] via one-hot masked sum.
-    table (width,), idx (Q,) -> (Q,).  Out-of-range idx yields 0."""
-    iota = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], width), 1)
-    onehot = idx[:, None] == iota
-    return jnp.sum(jnp.where(onehot, table[None, :], 0), axis=1)
 
 
 def _ef_kernel(base_ref, done_ref, val0_ref, i0_ref, cnt_ref, i1_ref,
@@ -63,30 +57,30 @@ def _ef_kernel(base_ref, done_ref, val0_ref, i0_ref, cnt_ref, i1_ref,
 
     @pl.when(k == 0)
     def _init():
-        zero = jnp.zeros((TILE_Q,), jnp.int32)
-        t_sc[0, :] = zero
-        found_sc[0, :] = zero
-        flow_sc[0, :] = zero
-        li1_sc[0, :] = zero
-        carry_sc[0, :] = zero
+        zero = jnp.zeros((1, TILE_Q), jnp.int32)
+        t_sc[...] = zero
+        found_sc[...] = zero
+        flow_sc[...] = zero
+        li1_sc[...] = zero
+        carry_sc[...] = zero
 
     cur0 = (base_ref[i] + k) * EF_PAGE        # global word id of page start
-    pg = pg_ref[0, :]                         # (EF_PAGE,) resident words
-    i0 = i0_ref[0, :]
-    cnt = cnt_ref[0, :]
-    i1 = i1_ref[0, :]
-    i1m = i1m_ref[0, :]
-    l = l_ref[0, :]
-    xlo = xlo_ref[0, :]
-    gb0 = gb0_ref[0, :]
-    carry = carry_sc[0, :]
+    pg = pg_ref[...]                          # (1, EF_PAGE) resident words
+    i0 = i0_ref[...]                          # (1, TILE_Q) lane rows
+    cnt = cnt_ref[...]
+    i1 = i1_ref[...]
+    i1m = i1m_ref[...]
+    l = l_ref[...]
+    xlo = xlo_ref[...]
+    gb0 = gb0_ref[...]
+    carry = carry_sc[...]
 
     def read_word(wi):
         # global word index -> value: resident page, else the previous
         # page's last word (carry), else 0 (only reached masked)
         off = wi - cur0
         in_pg = (off >= 0) & (off < EF_PAGE)
-        v = _gather(pg, jnp.where(in_pg, off, -1), EF_PAGE)
+        v = row_gather(pg, jnp.where(in_pg, off, -1))
         return jnp.where(off == -1, carry, v)
 
     def body(_, st):
@@ -112,20 +106,19 @@ def _ef_kernel(base_ref, done_ref, val0_ref, i0_ref, cnt_ref, i1_ref,
 
     t, found, flow, li1 = lax.fori_loop(
         0, max_win, body,
-        (t_sc[0, :], found_sc[0, :], flow_sc[0, :], li1_sc[0, :]))
-    t_sc[0, :] = t
-    found_sc[0, :] = found
-    flow_sc[0, :] = flow
-    li1_sc[0, :] = li1
-    carry_sc[0, :] = jnp.full((TILE_Q,), pg[EF_PAGE - 1], jnp.int32)
+        (t_sc[...], found_sc[...], flow_sc[...], li1_sc[...]))
+    t_sc[...] = t
+    found_sc[...] = found
+    flow_sc[...] = flow
+    li1_sc[...] = li1
+    carry_sc[...] = jnp.broadcast_to(pg[:, EF_PAGE - 1:], (1, TILE_Q))
 
     @pl.when(k == k_pages - 1)
     def _flush():
-        hfin = jnp.where(found != 0, hx_ref[0, :], hi1_ref[0, :])
+        hfin = jnp.where(found != 0, hx_ref[...], hi1_ref[...])
         lowe = jnp.where(found != 0, flow, li1)
         val = lax.shift_left(hfin, l) | lowe
-        out_ref[0, :] = jnp.where(done_ref[0, :] != 0,
-                                  val0_ref[0, :], val)
+        out_ref[...] = jnp.where(done_ref[...] != 0, val0_ref[...], val)
 
 
 def ef_intersect_pallas(tile_base: jax.Array, done: jax.Array,
@@ -140,13 +133,14 @@ def ef_intersect_pallas(tile_base: jax.Array, done: jax.Array,
     ``tile_base`` (Q // TILE_Q,) int32 — first low-bits page each tile may
     touch; the remaining query arrays are (Q,) int32 lanes sorted by first
     page with their host-computed probe state; ``lo_pg``
-    (num_pages, EF_PAGE) is the paged packed low-bits array.  Returns (Q,)
+    (num_pages, 1, EF_PAGE) is the paged packed low-bits array.  Returns (Q,)
     int32 next_geq values, bit-exact vs ``core.ef.ef_next_geq_np``."""
     Q = done.shape[0]
     kernel = lambda *refs: _ef_kernel(*refs, max_win=max_win,
                                       k_pages=k_pages)
     qspec = pl.BlockSpec((1, TILE_Q), lambda i, k, b: (0, i))
-    pgspec = pl.BlockSpec((1, EF_PAGE), lambda i, k, b: (b[i] + k, 0))
+    pgspec = pl.BlockSpec((None, 1, EF_PAGE),
+                          lambda i, k, b: (b[i] + k, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(Q // TILE_Q, k_pages),

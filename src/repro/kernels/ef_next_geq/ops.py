@@ -22,18 +22,19 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import count_launch
 from ...core.ef import EFStore, ef_probe_state_np
 from .ef_next_geq import EF_PAGE, TILE_Q, ef_intersect_pallas
 
 
 def pad_ef_operands(store: EFStore) -> tuple[jax.Array, dict]:
-    """Page the packed low-bits words to (num_pages, EF_PAGE) int32.
+    """Page the packed low-bits words to (num_pages, 1, EF_PAGE) int32.
     Compute once per index (PallasEngine caches this in its EF pack)."""
     wl = int(store.lo_words.size)
     num_pages = max(1, -(-wl // EF_PAGE))
     pg = np.zeros(num_pages * EF_PAGE, dtype=np.uint32)
     pg[:wl] = store.lo_words
-    tables = jnp.asarray(pg.view(np.int32).reshape(num_pages, EF_PAGE))
+    tables = jnp.asarray(pg.view(np.int32).reshape(num_pages, 1, EF_PAGE))
     statics = dict(max_win=int(store.max_bucket) + 1, num_pages=num_pages)
     return tables, statics
 
@@ -126,6 +127,7 @@ def next_geq_ef(tables: jax.Array, statics: dict, store: EFStore,
         return np.zeros(0, np.int32)
     order, base, k_pages, lanes = route_low_pages(
         store, rank_pg, list_ids, xs, statics["num_pages"])
+    count_launch("ef_next_geq", interpret)
     out = _ef_call(tables, jnp.asarray(base),
                    *(jnp.asarray(lanes[k]) for k in _LANE_KEYS),
                    max_win=statics["max_win"], k_pages=k_pages,

@@ -31,10 +31,12 @@ innermost, so scratch written at step (i, k) is live at (i, k+1)):
      whole (the paper's "dictionary fits in RAM", one level down) since
      they are O(S), not O(N).
 
-Table lookups use masked-sum one-hot gathers (same idiom as
-``grammar_expand``) because arbitrary dynamic gathers from VMEM do not
-vectorize on the TPU — exact in int32.  The widest stream-side compare is
-(TILE_Q, PAGE); the old (TILE_Q, N) full-stream broadcast is gone.
+Table lookups are masked-sum one-hot gathers (``kernels.gather``): the
+resident page with one (TILE_Q, PAGE) compare, the list and grammar
+tables one 128-lane row at a time, so VMEM stays bounded however many
+rules the grammar has.  The stream arrives as ``(num_pages, 1, PAGE)`` so
+each page block's last two dims equal the array's (the TPU block-shape
+rule); the leading page dim is squeezed away inside the kernel.
 """
 
 from __future__ import annotations
@@ -44,16 +46,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..gather import row_gather, table_gather
+
 TILE_Q = 128
 INT_INF = 2**31 - 1  # plain int: jnp array constants can't be captured
-
-
-def _gather(table: jax.Array, idx: jax.Array, width: int) -> jax.Array:
-    """Exact int32 gather table[idx] via one-hot masked sum.
-    table (width,), idx (Q,) -> (Q,).  Out-of-range idx yields 0."""
-    iota = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], width), 1)
-    onehot = idx[:, None] == iota
-    return jnp.sum(jnp.where(onehot, table[None, :], 0), axis=1)
 
 
 def _paged_intersect_kernel(base_ref, slots_ref, lids_ref, xs_ref,
@@ -62,19 +58,18 @@ def _paged_intersect_kernel(base_ref, slots_ref, lids_ref, xs_ref,
                             ssum_ref, csyms_ref, csums_ref, out_ref,
                             pos_sc, s_sc, val_sc, done_sc, *,
                             max_scan: int, max_depth: int, T: int,
-                            page: int, k_pages: int,
-                            l1_pad: int, l_pad: int, s_pad: int):
+                            page: int, k_pages: int):
     i = pl.program_id(0)
     k = pl.program_id(1)
-    lid = lids_ref[0, :]                       # (TILE_Q,)
-    x = xs_ref[0, :]
-    end = _gather(starts_ref[0, :], lid + 1, l1_pad)
+    lid = lids_ref[...]                        # (1, TILE_Q) lane rows
+    x = xs_ref[...]
+    end = table_gather(starts_ref, lid + 1)
 
     @pl.when(k == 0)
     def _init():
-        pos = pos0_ref[0, :]
-        s = s0_ref[0, :]
-        last = _gather(lasts_ref[0, :], lid, l_pad)
+        pos = pos0_ref[...]
+        s = s0_ref[...]
+        last = table_gather(lasts_ref, lid)
         # lanes that need no page data settle immediately: the start state
         # already answers (s >= x, covers the head case), the suffix is
         # empty (pos >= end), or x exceeds the list entirely.
@@ -82,77 +77,80 @@ def _paged_intersect_kernel(base_ref, slots_ref, lids_ref, xs_ref,
         done = done_early | (pos >= end) | (x > last)
         val = jnp.where(done_early, s, INT_INF)
         val = jnp.where(x > last, INT_INF, val)
-        pos_sc[0, :] = pos
-        s_sc[0, :] = s
-        val_sc[0, :] = jnp.where(done, val, INT_INF)
-        done_sc[0, :] = done.astype(jnp.int32)
+        pos_sc[...] = pos
+        s_sc[...] = s
+        val_sc[...] = jnp.where(done, val, INT_INF)
+        done_sc[...] = done.astype(jnp.int32)
 
-    cur = base_ref[i] + k                      # GLOBAL page id (offset math
-    #                                            stays in stream coordinates;
-    #                                            slots_ref only steers which
-    #                                            storage row the DMA reads)
-    pos = pos_sc[0, :]
-    s = s_sc[0, :]
-    done = done_sc[0, :] != 0
-    anchor = pos0_ref[0, :]
-    csums = csums_ref[0, :]                    # (PAGE,) resident page
-    csyms = csyms_ref[0, :]
+    # a tile whose lanes have all settled has nothing left to read: its
+    # remaining page steps leave every scratch row as it is
+    @pl.when(jnp.min(done_sc[...]) == 0)
+    def _page():
+        # GLOBAL page id: offset math stays in stream coordinates;
+        # slots_ref only steers which storage row the DMA reads
+        cur = base_ref[i] + k
+        pos = pos_sc[...]
+        s = s_sc[...]
+        done = done_sc[...] != 0
+        anchor = pos0_ref[...]
+        csums = csums_ref[...]                 # (1, PAGE) resident page
+        csyms = csyms_ref[...]
 
-    # -- phrase-sum skipping, masked to the resident page ------------------
-    # total advancement is capped at max_scan from the anchor — the same
-    # trip budget as the flat reference, and what bounds the page router's
-    # window to (anchor + max_scan) // PAGE.
-    def scan_body(_, ps_state):
-        pos, s = ps_state
+        # -- phrase-sum skipping, masked to the resident page --------------
+        # total advancement is capped at max_scan from the anchor — the
+        # same trip budget as the flat reference, and what bounds the page
+        # router's window to (anchor + max_scan) // PAGE.
+        def scan_body(_, ps_state):
+            pos, s = ps_state
+            off = pos - cur * page
+            in_page = (off >= 0) & (off < page)
+            ps = row_gather(csums, jnp.where(in_page, off, -1))
+            take = (~done & in_page & (pos < end)
+                    & (pos - anchor < max_scan) & (s + ps < x))
+            return (pos + jnp.where(take, 1, 0),
+                    s + jnp.where(take, ps, 0))
+
+        pos, s = jax.lax.fori_loop(0, min(max_scan, page), scan_body,
+                                   (pos, s))
+
+        # a lane is settled by this page iff it halted inside it (the skip
+        # window can straddle pages: a lane parked on the page edge resumes
+        # next step) or ran out of list.
         off = pos - cur * page
         in_page = (off >= 0) & (off < page)
-        ps = _gather(csums, jnp.where(in_page, off, -1), page)
-        take = (~done & in_page & (pos < end) & (pos - anchor < max_scan)
-                & (s + ps < x))
-        return (pos + jnp.where(take, 1, 0), s + jnp.where(take, ps, 0))
+        past_end = pos >= end
+        newly = ~done & (in_page | past_end)
+        done_early = s >= x
 
-    pos, s = jax.lax.fori_loop(0, min(max_scan, page), scan_body, (pos, s))
+        # -- fixed-depth grammar descent inside the resident page ----------
+        sym0 = row_gather(csyms, jnp.where(in_page, off, -1))
 
-    # a lane is settled by this page iff it halted inside it (the skip
-    # window can straddle pages: a lane parked on the page edge resumes
-    # next step) or ran out of list.
-    off = pos - cur * page
-    in_page = (off >= 0) & (off < page)
-    past_end = pos >= end
-    newly = ~done & (in_page | past_end)
-    done_early = s >= x
+        def descend_body(_, state):
+            sym, s = state
+            is_rule = sym >= T
+            l = jnp.where(is_rule, table_gather(sleft_ref, sym), sym)
+            r = jnp.where(is_rule, table_gather(sright_ref, sym), sym)
+            ls = table_gather(ssum_ref, l)
+            go_left = s + ls >= x
+            new_sym = jnp.where(go_left, l, r)
+            new_s = jnp.where(go_left, s, s + ls)
+            return (jnp.where(is_rule, new_sym, sym),
+                    jnp.where(is_rule, new_s, s))
 
-    # -- fixed-depth grammar descent inside the resident page --------------
-    sleft = sleft_ref[0, :]
-    sright = sright_ref[0, :]
-    ssum = ssum_ref[0, :]
-    sym0 = _gather(csyms, jnp.where(in_page, off, -1), page)
+        sym_f, s_f = jax.lax.fori_loop(0, max_depth, descend_body,
+                                       (sym0, s))
+        answer = s_f + table_gather(ssum_ref, sym_f)
 
-    def descend_body(_, state):
-        sym, s = state
-        is_rule = sym >= T
-        l = jnp.where(is_rule, _gather(sleft, sym, s_pad), sym)
-        r = jnp.where(is_rule, _gather(sright, sym, s_pad), sym)
-        ls = _gather(ssum, l, s_pad)
-        go_left = s + ls >= x
-        new_sym = jnp.where(go_left, l, r)
-        new_s = jnp.where(go_left, s, s + ls)
-        return (jnp.where(is_rule, new_sym, sym),
-                jnp.where(is_rule, new_s, s))
-
-    sym_f, s_f = jax.lax.fori_loop(0, max_depth, descend_body, (sym0, s))
-    answer = s_f + _gather(ssum, sym_f, s_pad)
-
-    val = jnp.where(done_early, s, answer)
-    val = jnp.where(past_end & ~done_early, INT_INF, val)
-    val_sc[0, :] = jnp.where(newly, val, val_sc[0, :])
-    done_sc[0, :] = (done | newly).astype(jnp.int32)
-    pos_sc[0, :] = pos
-    s_sc[0, :] = s
+        val = jnp.where(done_early, s, answer)
+        val = jnp.where(past_end & ~done_early, INT_INF, val)
+        val_sc[...] = jnp.where(newly, val, val_sc[...])
+        done_sc[...] = (done | newly).astype(jnp.int32)
+        pos_sc[...] = pos
+        s_sc[...] = s
 
     @pl.when(k == k_pages - 1)
     def _flush():
-        out_ref[0, :] = val_sc[0, :]
+        out_ref[...] = val_sc[...]
 
 
 def paged_intersect_pallas(tile_base: jax.Array, tile_slots: jax.Array,
@@ -176,21 +174,19 @@ def paged_intersect_pallas(tile_base: jax.Array, tile_slots: jax.Array,
     stays in global stream coordinates either way, only the BlockSpec
     index_map reads the remap); ``lids/xs/pos0/s0`` (Q,) int32 queries
     sorted by anchor page with their bucket-lookup start state;
-    ``csyms_pg/csums_pg`` (num_rows, PAGE) paged stream or pool;
-    remaining tables 1-D lane-padded.
+    ``csyms_pg/csums_pg`` (num_rows, 1, PAGE) paged stream or pool;
+    ``starts/lasts/sleft/sright/ssum`` ``gather.pack_table`` tables.
     Returns (Q,) int32 next_geq values (INT_INF past the end), bit-exact vs
     ``engine.jnp_backend.next_geq_batch_paged``."""
     Q = lids.shape[0]
-    page = csyms_pg.shape[1]
-    dims = dict(l1_pad=starts.shape[0], l_pad=lasts.shape[0],
-                s_pad=ssum.shape[0])
+    page = csyms_pg.shape[-1]
     kernel = lambda *refs: _paged_intersect_kernel(
         *refs, max_scan=max_scan, max_depth=max_depth, T=T, page=page,
-        k_pages=k_pages, **dims)
+        k_pages=k_pages)
     qspec = pl.BlockSpec((1, TILE_Q), lambda i, k, b, sl: (0, i))
-    tspec = lambda a: pl.BlockSpec((1, a.shape[0]),
-                                   lambda i, k, b, sl: (0, 0))
-    pgspec = pl.BlockSpec((1, page), lambda i, k, b, sl: (sl[i, k], 0))
+    tspec = lambda a: pl.BlockSpec(a.shape, lambda i, k, b, sl: (0, 0))
+    pgspec = pl.BlockSpec((None, 1, page),
+                          lambda i, k, b, sl: (sl[i, k], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(Q // TILE_Q, k_pages),
@@ -207,6 +203,5 @@ def paged_intersect_pallas(tile_base: jax.Array, tile_slots: jax.Array,
         out_shape=jax.ShapeDtypeStruct((1, Q), jnp.int32),
         interpret=interpret,
     )(tile_base, tile_slots, lids[None, :], xs[None, :], pos0[None, :],
-      s0[None, :],
-      starts[None, :], lasts[None, :], sleft[None, :], sright[None, :],
-      ssum[None, :], csyms_pg, csums_pg)[0]
+      s0[None, :], starts, lasts, sleft, sright, ssum, csyms_pg,
+      csums_pg)[0]

@@ -29,16 +29,17 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .. import should_interpret
+from .. import count_launch, should_interpret
 from ...core.jax_index import (FlatIndex, PagedIndex, build_paged_index,
                                INT_INF)
+from ..gather import pack_table
 from .list_intersect import TILE_Q, paged_intersect_pallas
 
 
-def _pad1(a: jax.Array, mult: int = 128) -> jax.Array:
-    n = a.shape[0]
-    np_ = max(mult, -(-n // mult) * mult)
-    return jnp.zeros(np_, jnp.int32).at[:n].set(a.astype(jnp.int32))
+def paged_rows(pg: jax.Array) -> jax.Array:
+    """(num_pages, PAGE) stream table -> the kernels' (num_pages, 1, PAGE)
+    int32 layout (one page per block, block dims equal to the array's)."""
+    return pg.astype(jnp.int32).reshape(pg.shape[0], 1, pg.shape[-1])
 
 
 def routing_snapshot(pi: PagedIndex) -> dict:
@@ -65,20 +66,18 @@ def routing_snapshot(pi: PagedIndex) -> dict:
 
 def pad_paged_operands(pi: PagedIndex, include_stream: bool = True
                        ) -> tuple[tuple[jax.Array, ...], dict, dict]:
-    """Kernel operand pack for one paged index: device tables (lane-padded
-    broadcast tables + the paged stream), static bounds, and the numpy
+    """Kernel operand pack for one paged index: device tables (the list
+    and grammar tables packed ``(rows, 128)`` + the paged stream in its
+    ``(num_pages, 1, PAGE)`` kernel layout), static bounds, and the numpy
     routing snapshot.  Compute once per index (PallasEngine caches this at
     construction).  ``include_stream=False`` omits the two paged stream
     tables — the out-of-core path substitutes the resident pool per launch
     (DESIGN.md §11.2)."""
     fl = pi.flat
-    tables = (
-        _pad1(fl.starts), _pad1(fl.lasts),
-        _pad1(fl.sym_left), _pad1(fl.sym_right), _pad1(fl.sym_sum),
-    )
+    tables = tuple(pack_table(a) for a in (
+        fl.starts, fl.lasts, fl.sym_left, fl.sym_right, fl.sym_sum))
     if include_stream:
-        tables += (pi.c_syms_pg.astype(jnp.int32),
-                   pi.c_sums_pg.astype(jnp.int32))
+        tables += (paged_rows(pi.c_syms_pg), paged_rows(pi.c_sums_pg))
     statics = dict(max_scan=fl.max_scan, max_depth=fl.max_depth,
                    T=fl.num_terminals)
     return tables, statics, routing_snapshot(pi)
@@ -179,6 +178,19 @@ def route_pages(host: dict, list_ids: np.ndarray, xs: np.ndarray):
             pos0[take].astype(np.int32), s0[take].astype(np.int32))
 
 
+#: bytes of scalar-prefetched operands one launch may put in SMEM (1 MiB
+#: on v5e, where a (tiles, k_pages) int32 table pads its rows to 128
+#: lanes: 2,048 tiles at k_pages 64 already overflow it)
+SMEM_BUDGET = 512 * 1024
+
+
+def tiles_per_launch(k_pages: int) -> int:
+    """Most tiles (a power of two) whose ``tile_slots`` rows fit
+    ``SMEM_BUDGET``."""
+    row = 4 * (-(-k_pages // 128) * 128)
+    return 1 << ((SMEM_BUDGET // row).bit_length() - 1)
+
+
 @partial(jax.jit, static_argnames=("max_scan", "max_depth", "T", "k_pages",
                                    "interpret"))
 def _paged_call(tables: tuple[jax.Array, ...], tile_base: jax.Array,
@@ -211,21 +223,29 @@ def _launch_routed(tables, host, list_ids, xs, *, max_scan, max_depth, T,
     tile_pages = base[:, None].astype(np.int64) + np.arange(k_pages)
     if resident is None:
         tile_slots = tile_pages.astype(np.int32)
-        csyms, csums = tables[5], tables[6]
     else:
         resident.ensure(probe_working_set(host, list_ids, xs))
         tile_slots = np.maximum(
             resident.slot_of_page[tile_pages], 0).astype(np.int32)
         csyms, csums, _ = resident.device_tables()
-        tables = tables[:5] + (csyms, csums)
-    out = _paged_call(tables, jnp.asarray(base), jnp.asarray(tile_slots),
-                      jnp.asarray(lids_s), jnp.asarray(xs_s),
-                      jnp.asarray(pos0_s), jnp.asarray(s0_s),
-                      max_scan=max_scan, max_depth=max_depth, T=T,
-                      k_pages=k_pages, interpret=interpret)
+        tables = tables[:5] + (paged_rows(csyms), paged_rows(csums))
+    # one launch per chunk of tiles, so the scalar-prefetched page table
+    # fits the chip's SMEM; every chunk is enqueued before any is read
+    step = tiles_per_launch(k_pages)
+    outs = []
+    for t in range(0, base.shape[0], step):
+        lanes = slice(t * TILE_Q, (t + step) * TILE_Q)
+        count_launch("list_intersect", interpret)
+        outs.append(_paged_call(
+            tables, jnp.asarray(base[t:t + step]),
+            jnp.asarray(tile_slots[t:t + step]), jnp.asarray(lids_s[lanes]),
+            jnp.asarray(xs_s[lanes]), jnp.asarray(pos0_s[lanes]),
+            jnp.asarray(s0_s[lanes]), max_scan=max_scan,
+            max_depth=max_depth, T=T, k_pages=k_pages, interpret=interpret))
+    out = np.concatenate([np.asarray(o) for o in outs])
     unsort = np.empty(q, np.int64)
     unsort[order] = np.arange(q)
-    return np.asarray(out)[:q][unsort]
+    return out[:q][unsort]
 
 
 def next_geq_paged(tables: tuple[jax.Array, ...], host: dict,

@@ -1,12 +1,14 @@
 """Wrappers for the grid-blocked page-entry decode kernel.
 
-``pad_score_operands(pi)`` packs the device tables once per index —
-lane-padded grammar tables, the paged symbol/phrase-sum streams the
-probe kernel already keeps, plus one NEW paged table: the per-symbol
-expansion lengths (``sym_len[c]``) page-gathered on host, so the kernel
-reads element counts with the same one-page DMA discipline as values
-(gathering ``sym_len`` by symbol id in-kernel would cost a (PAGE, S)
-one-hot per instance; the pre-gathered page row costs nothing).
+``pad_score_operands(pi)`` packs the device tables once per index — the
+grammar tables packed ``(rows, 128)``, the paged symbol stream the probe
+kernel already keeps, and two paged tables built on the host: each page's
+inclusive prefix sums of the phrase sums (``sym_sum[c]``) and of the
+per-symbol expansion lengths (``sym_len[c]``).  The kernel reads both
+with the same one-page DMA discipline as values (gathering ``sym_len`` by
+symbol id in-kernel would cost a (PAGE, S) one-hot per instance) and
+rebases them at the entry's window instead of running a cumsum, which
+Mosaic does not lower.
 
 ``page_decode(...)`` is the numpy-in/numpy-out launch the engine calls
 per ScoreRound.
@@ -21,9 +23,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .. import should_interpret
+from .. import count_launch, should_interpret
 from ...core.jax_index import PagedIndex
-from ..list_intersect.ops import _pad1
+from ..gather import pack_table
+from ..list_intersect.ops import paged_rows
 from .page_score import TILE_B, page_decode_pallas
 
 
@@ -33,15 +36,21 @@ def pad_score_operands(pi: PagedIndex) -> tuple[tuple[jax.Array, ...], dict]:
     ranked query."""
     fl = pi.flat
     c = np.asarray(fl.c, np.int64)
-    lens = np.asarray(fl.sym_len, np.int32)[c]
     page = pi.page_size
     pad = pi.num_pages * page - c.size
-    clens_pg = jnp.asarray(np.pad(lens, (0, pad)).reshape(-1, page))
+
+    def page_prefix(per_sym):
+        # int64 cumsum wrapped to int32: the kernel only ever takes
+        # differences inside one list's window, which fit int32
+        v = np.pad(np.asarray(per_sym, np.int64)[c], (0, pad))
+        return paged_rows(jnp.asarray(
+            np.cumsum(v.reshape(-1, page), axis=1).astype(np.int32)))
+
     tables = (
-        _pad1(fl.sym_left), _pad1(fl.sym_right), _pad1(fl.sym_sum),
-        _pad1(fl.sym_len),
-        pi.c_syms_pg.astype(jnp.int32), pi.c_sums_pg.astype(jnp.int32),
-        clens_pg,
+        *(pack_table(a) for a in (fl.sym_left, fl.sym_right, fl.sym_sum,
+                                  fl.sym_len)),
+        paged_rows(pi.c_syms_pg), page_prefix(fl.sym_sum),
+        page_prefix(fl.sym_len),
     )
     statics = dict(max_depth=fl.max_depth, T=fl.num_terminals)
     return tables, statics
@@ -52,11 +61,9 @@ def _call(tables: tuple[jax.Array, ...], pages: jax.Array, slo: jax.Array,
           nsym: jax.Array, base: jax.Array, head: jax.Array,
           cnt: jax.Array, *,
           max_depth: int, T: int, b_pad: int, interpret: bool) -> jax.Array:
-    sleft, sright, ssum, slen, csyms_pg, csums_pg, clens_pg = tables
     return page_decode_pallas(
-        pages, slo, nsym, base, head, cnt, sleft, sright, ssum, slen,
-        csyms_pg, csums_pg, clens_pg, max_depth=max_depth, T=T,
-        b_pad=b_pad, interpret=interpret)
+        pages, slo, nsym, base, head, cnt, *tables, max_depth=max_depth,
+        T=T, b_pad=b_pad, interpret=interpret)
 
 
 def page_decode(tables: tuple[jax.Array, ...], statics: dict,
@@ -71,6 +78,7 @@ def page_decode(tables: tuple[jax.Array, ...], statics: dict,
         interpret = should_interpret()
     if b_pad % TILE_B:
         raise ValueError(f"b_pad {b_pad} not a multiple of {TILE_B}")
+    count_launch("page_score", interpret)
     out = _call(tables, jnp.asarray(pages, jnp.int32),
                 jnp.asarray(slo, jnp.int32), jnp.asarray(nsym, jnp.int32),
                 jnp.asarray(base, jnp.int32), jnp.asarray(head, jnp.int32),

@@ -14,23 +14,29 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from .. import should_interpret
+from .. import count_launch, should_interpret
 from .pair_count import TILE_N, pair_count_pallas
 
 
-def _pair_stream(seq: jax.Array, active: jax.Array, n: jax.Array):
-    """(a, b, valid) for every adjacent pair slot, zero-padded to a
-    TILE_N multiple.  Mirrors the device builders' pair semantics: a slot
-    is valid iff both positions are active and inside the live length."""
-    Np = seq.shape[0]
+def tile_stream(x: jax.Array) -> jax.Array:
+    """(Np,) -> the kernel's (num_tiles, 1, tn) int32 layout, zero-padded
+    to a tile multiple."""
+    Np = x.shape[0]
     tn = min(TILE_N, Np)
     pad = -(-Np // tn) * tn - Np
+    return jnp.pad(x.astype(jnp.int32), (0, pad)).reshape(-1, 1, tn)
+
+
+def _pair_stream(seq: jax.Array, active: jax.Array, n: jax.Array):
+    """(a, b, valid) for every adjacent pair slot, tiled.  Mirrors the
+    device builders' pair semantics: a slot is valid iff both positions
+    are active and inside the live length."""
+    Np = seq.shape[0]
     idx = jnp.arange(Np, dtype=jnp.int32)
     b = jnp.concatenate([seq[1:], jnp.zeros((1,), seq.dtype)])
     b_act = jnp.concatenate([active[1:], jnp.zeros((1,), bool)])
-    vm = (active & b_act & (idx + 1 < n)).astype(jnp.int32)
-    ext = lambda x: jnp.pad(x.astype(jnp.int32), (0, pad)).reshape(-1, tn)
-    return ext(seq), ext(b), ext(vm)
+    vm = active & b_act & (idx + 1 < n)
+    return tile_stream(seq), tile_stream(b), tile_stream(vm)
 
 
 @partial(jax.jit, static_argnames=("interpret",))
@@ -49,6 +55,7 @@ def pair_count(seq: jax.Array, active: jax.Array, n,
     (use -1 sentinels for unused slots)."""
     if interpret is None:
         interpret = should_interpret()
+    count_launch("pair_count", interpret)
     return _pair_count_jit(jnp.asarray(seq), jnp.asarray(active),
                            jnp.asarray(n, jnp.int32), jnp.asarray(cand_a),
                            jnp.asarray(cand_b), interpret=interpret)

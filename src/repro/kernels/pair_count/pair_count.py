@@ -3,7 +3,7 @@
 The construction-time hot loop of Re-Pair (DESIGN.md §3.3): count, for a
 static table of K candidate pairs, every adjacent occurrence
 ``(seq[i], seq[i+1])`` across the working sequence.  The sequence lives in
-HBM as fixed-size tiles ``(num_tiles, TILE_N)`` — the same paging
+HBM as fixed-size tiles ``(num_tiles, 1, TILE_N)`` — the same paging
 discipline as ``list_intersect``: each kernel instance sees exactly ONE
 sequence tile and one candidate tile, so per-instance VMEM is a function
 of ``TILE_K`` and ``TILE_N``, never of the stream length N.
@@ -53,11 +53,12 @@ def pair_count_pallas(cand_a: jax.Array, cand_b: jax.Array,
     """Histogram of K candidate pairs over a tiled pair stream.
 
     ``cand_a``/``cand_b`` (K,) int32 with -1 sentinels; ``pa_t``/``pb_t``/
-    ``vm_t`` (num_tiles, TILE_N) int32 — left symbol, right symbol and
-    validity of every adjacent pair slot.  Returns (K,) int32 exact
-    counts, bit-identical to the jnp sort histogram (``ref.py``)."""
+    ``vm_t`` (num_tiles, 1, TILE_N) int32 — left symbol, right symbol and
+    validity of every adjacent pair slot, one sequence tile per block.
+    Returns (K,) int32 exact counts, bit-identical to the jnp sort
+    histogram (``ref.py``)."""
     K = cand_a.shape[0]
-    nt, tn = pa_t.shape
+    nt, _, tn = pa_t.shape
     tk = min(TILE_K, K)
     # the grid must cover every candidate: pad the table to a tile
     # multiple with -1 sentinels (a partial tail tile would otherwise be
@@ -68,7 +69,7 @@ def pair_count_pallas(cand_a: jax.Array, cand_b: jax.Array,
         cand_b = jnp.pad(cand_b, (0, pad), constant_values=-1)
     kp = K + pad
     cspec = pl.BlockSpec((1, tk), lambda kt, t: (0, kt))
-    sspec = pl.BlockSpec((1, tn), lambda kt, t: (t, 0))
+    sspec = pl.BlockSpec((None, 1, tn), lambda kt, t: (t, 0, 0))
     return pl.pallas_call(
         _pair_count_kernel,
         grid=(kp // tk, nt),

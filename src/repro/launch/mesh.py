@@ -16,10 +16,19 @@ from __future__ import annotations
 import jax
 
 
+def _auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...]
+               ) -> jax.sharding.Mesh:
+    # Auto axes: the sharded programs here place arrays with explicit
+    # PartitionSpecs and shard_map, not with jax.make_mesh's Explicit
+    # default (which requires a jax.set_mesh context around every jit)
+    return jax.make_mesh(shape, axes, axis_types=(
+        jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
@@ -27,7 +36,7 @@ def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
     n = len(jax.devices())
     data = min(data, n)
     model = max(1, min(model, n // data))
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def data_axes(mesh: jax.sharding.Mesh) -> tuple[str, ...]:

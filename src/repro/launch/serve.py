@@ -19,6 +19,42 @@ import time
 import numpy as np
 
 
+#: score-directory page size of the ranked workload: a fine directory
+#: whose pages can be pruned (it divides every engine's stream page)
+SCORE_PAGE = 128
+
+
+def _zipf_sampler(lists, rng):
+    """Draw k distinct term ids, Zipf(1.1) over lists ranked longest
+    first — the query-term skew of the serving workloads."""
+    order = sorted(range(len(lists)), key=lambda i: -len(lists[i]))
+    p = np.arange(1, len(lists) + 1, dtype=np.float64) ** -1.1
+    p /= p.sum()
+    return lambda k: [int(order[r]) for r in
+                      rng.choice(len(lists), size=k, replace=False, p=p)]
+
+
+def boolean_workload(lists, n: int, seed: int = 1) -> list[str]:
+    """The launcher's boolean mix: 70% conjunctions of 2-3 Zipf terms,
+    30% ``(a AND b) OR NOT c``."""
+    rng = np.random.default_rng(seed)
+    draw = _zipf_sampler(lists, rng)
+    qs = []
+    for _ in range(n):
+        ts = draw(int(rng.integers(2, 4)))
+        qs.append(" AND ".join(str(t) for t in ts)
+                  if rng.random() < 0.7 else
+                  f"({ts[0]} AND {ts[1]}) OR NOT {ts[-1]}")
+    return qs
+
+
+def ranked_workload(lists, n: int, seed: int = 2) -> list[list[int]]:
+    """The launcher's ranked mix: bags of 2-4 Zipf terms."""
+    rng = np.random.default_rng(seed)
+    draw = _zipf_sampler(lists, rng)
+    return [draw(int(nk)) for nk in rng.integers(2, 5, size=n)]
+
+
 def serve_queries(n_queries: int, engine: str = "jnp",
                   data_shards: int = 0, builder: str = "host",
                   refreshes: int = 0, query: str | None = None,
@@ -113,21 +149,7 @@ def serve_queries(n_queries: int, engine: str = "jnp",
     # rounds of concurrent queries merge into shared device dispatches
     if concurrency:
         from ..query import naive_eval
-        rngq = np.random.default_rng(1)
-        order = sorted(range(len(lists)), key=lambda i: -len(lists[i]))
-        p = np.arange(1, len(lists) + 1, dtype=np.float64) ** -1.1
-        p /= p.sum()
-
-        def draw(k):
-            return [int(order[r]) for r in
-                    rngq.choice(len(lists), size=k, replace=False, p=p)]
-
-        qs = []
-        for _ in range(max(concurrency * 4, 16)):
-            ts = draw(int(rngq.integers(2, 4)))
-            qs.append(" AND ".join(str(t) for t in ts)
-                      if rngq.random() < 0.7 else
-                      f"({ts[0]} AND {ts[1]}) OR NOT {ts[-1]}")
+        qs = boolean_workload(lists, max(concurrency * 4, 16))
         import os
         if batch_window is None and "REPRO_BATCH_WINDOW" not in os.environ:
             # window defaults to the offered concurrency; an explicit
@@ -166,14 +188,8 @@ def serve_queries(n_queries: int, engine: str = "jnp",
     # reports how many page decodes the admission bound refused
     if topk:
         from ..query import rank_oracle
-        srv.engine.score_page_size = 128   # fine directory: prunable pages
-        rngr = np.random.default_rng(2)
-        order = sorted(range(len(lists)), key=lambda i: -len(lists[i]))
-        p = np.arange(1, len(lists) + 1, dtype=np.float64) ** -1.1
-        p /= p.sum()
-        bags = [[int(order[r]) for r in
-                 rngr.choice(len(lists), size=int(nk), replace=False, p=p)]
-                for nk in rngr.integers(2, 5, size=16)]
+        srv.engine.score_page_size = SCORE_PAGE   # prunable pages
+        bags = ranked_workload(lists, 16)
         srv.search_topk(bags[0], topk)    # compile + build the score tier
         t0 = time.perf_counter()
         routs = srv.search_topk_many(bags, topk)
@@ -368,6 +384,8 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0,
                     help="corpus seed (the PostingsSource key)")
     args = ap.parse_args()
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.tier == "queries":
         serve_queries(args.n, args.engine, data_shards=args.data_shards,
                       builder=args.builder, refreshes=args.refresh,

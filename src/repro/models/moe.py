@@ -26,7 +26,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .layers import Dtype, dense
@@ -190,9 +189,9 @@ def moe_ffn_sharded(p: dict, x: jax.Array, *, n_experts: int, top_k: int,
             capacity_factor=capacity_factor, e_base=e_base,
             e_local=e_local, dp_axes_t=dp_axes_t, tp_axis=tp_axis)
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh, in_specs=(pspecs, xspec),
-        out_specs=(xspec, P()), check_rep=False)(p, x)
+        out_specs=(xspec, P()), check_vma=False)(p, x)
     return out, aux
 
 

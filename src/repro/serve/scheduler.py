@@ -695,6 +695,9 @@ class QueryScheduler:
             # key at the device boundary is (engine, codec, algo)
             "codec_dispatches": dict(
                 getattr(self._engine, "codec_dispatches", {})),
+            # work the live device engine sent to its host fallback by
+            # design (DeviceEngine.host_routes); empty on the host tier
+            "host_routes": dict(getattr(self._engine, "host_routes", {})),
             "decode_cache": self.decode_cache.stats(),
             "result_cache": self.result_cache.stats(),
             # the live engine's own decoded-list LRU (the layer under the

@@ -120,6 +120,22 @@ def test_device_host_fallback_routes_long_shorts(eres, elists):
         out, np.intersect1d(elists[big[0]], elists[big[1]]))
 
 
+def test_host_routes_are_counted(eres, elists):
+    """Pairs and k-term queries whose shortest list passes
+    ``max_short_len`` go to the host by design; each is counted, and the
+    scheduler's stats carry the counts."""
+    from repro.serve.scheduler import QueryScheduler
+    eng = JnpEngine(eres, max_short_len=4)
+    big = sorted(range(len(elists)), key=lambda i: -len(elists[i]))[:3]
+    small = min(range(len(elists)), key=lambda i: len(elists[i]))
+    assert eng.host_routes == {"pairs": 0, "multi": 0, "decodes": 0}
+    eng.intersect_pairs([(big[0], big[1]), (small, big[2])])
+    assert eng.host_routes["pairs"] == (len(elists[small]) > 4) + 1
+    eng.intersect_multi(big)
+    assert eng.host_routes["multi"] == 1
+    assert QueryScheduler(eng).stats()["host_routes"] == eng.host_routes
+
+
 def test_engine_registry():
     assert set(ENGINES) == {"host", "jnp", "pallas"}
     with pytest.raises(ValueError, match="unknown engine"):

@@ -20,7 +20,8 @@ _PROG = textwrap.dedent("""
                                             pipeline_apply,
                                             stack_mlp_params)
 
-    mesh = jax.make_mesh((4,), ("stage",))
+    mesh = jax.make_mesh((4,), ("stage",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     L, d, B, M = 8, 16, 12, 3
     params = stack_mlp_params(jax.random.key(0), L, d)
     x = jax.random.normal(jax.random.key(1), (B, d), jnp.float32)
@@ -57,7 +58,10 @@ def test_gpipe_matches_sequential_fwd_and_bwd():
         r = subprocess.run([sys.executable, "-c", _PROG],
                            capture_output=True, text=True, timeout=600,
                            env={"PYTHONPATH": "src",
-                                "PATH": "/usr/bin:/bin"})
+                                "PATH": "/usr/bin:/bin",
+                                # the child must not load the TPU runtime:
+                                # a chip belongs to one process
+                                "JAX_PLATFORMS": "cpu"})
     except subprocess.TimeoutExpired:
         pytest.xfail("gpipe subprocess exceeded 600s "
                      "(CPU-starved multi-device compile on this box)")

@@ -127,3 +127,15 @@ def test_postings_source_is_pure():
     assert len(a) == len(b)
     for x, y in zip(a, b):
         np.testing.assert_array_equal(x, y)
+
+
+def test_compile_cache_dir(monkeypatch):
+    """``JAX_COMPILATION_CACHE_DIR`` wins; without it the cache lives at
+    the fixed ``.jax_cache`` in the checkout, never a per-run path."""
+    from pathlib import Path
+    from repro.launch.compile_cache import compile_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert compile_cache_dir() == "/elsewhere/cache"
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    root = Path(__file__).resolve().parents[1]
+    assert compile_cache_dir() == str(root / ".jax_cache")

@@ -319,3 +319,31 @@ def test_result_cache_keying_across_modes(rlists, rres):
     again.docs = np.array([])       # mutate the returned object freely
     fresh = srv.search_topk("0 AND 1", 10)
     np.testing.assert_array_equal(fresh.docs, r10.docs)
+
+
+def test_launcher_topk_runs_page_score(rlists, rres):
+    """The launcher's ranked path — a pallas ``QueryServer`` with the fine
+    ``SCORE_PAGE`` directory — decodes its pages with the ``page_score``
+    kernel, which the launch counter shows, and stays oracle-exact."""
+    from repro.kernels import LAUNCHES
+    from repro.launch.serve import SCORE_PAGE
+    from repro.serve.query_serve import QueryServer
+    srv = QueryServer(rres, engine="pallas", interpret=True)
+    srv.engine.score_page_size = SCORE_PAGE
+    before = LAUNCHES["page_score[interpret]"]
+    bags = [[0, 1, 4], [2, 3]]
+    for bag, got in zip(bags, srv.search_topk_many(bags, 10)):
+        docs, scores = rank_oracle(rlists, rres.universe, bag, 10)
+        np.testing.assert_array_equal(got.docs, docs)
+        np.testing.assert_array_equal(got.scores, scores)
+    assert LAUNCHES["page_score[interpret]"] > before
+
+
+def test_pallas_refuses_foreign_score_geometry(rres):
+    """A score directory whose pages do not tile the pallas engine's stream
+    pages is refused, not decoded by another path."""
+    eng = PallasEngine(rres, max_short_len=64, interpret=True,
+                       page_size=256)
+    eng.score_page_size = 384
+    with pytest.raises(ValueError, match="does not divide"):
+        search_topk(eng, [0, 1], 10)
