@@ -40,6 +40,10 @@ import time
 from pathlib import Path
 
 KERNELS = ("list_intersect", "page_score", "ef_next_geq", "pair_count")
+VOCAB = 4000          # PostingsSource vocabulary
+PAIRS = 256           # conjunctive pairs in the pairs phase
+CONCURRENCY = 64      # scheduler batch window of the boolean phases
+BUILD_DOCS = 2000     # the launcher's default corpus, pallas-builder phase
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 CACHE_HIT = "/jax/compilation_cache/cache_hits"
 
@@ -86,12 +90,12 @@ def run_phase(meter, name: str, fn):
           + (f"; {detail}" if detail else ""), flush=True)
 
 
-def build_collection(num_docs: int, vocab: int, seed: int):
+def build_collection(num_docs: int, seed: int):
     """The launcher's corpus: ``PostingsSource`` version 0, inverted."""
     import numpy as np
     from repro.data.pipeline import PostingsSource
 
-    src = PostingsSource(base_docs=num_docs, vocab=vocab, seed=seed)
+    src = PostingsSource(base_docs=num_docs, vocab=VOCAB, seed=seed)
     inv: dict[int, list[int]] = {}
     for d, terms in enumerate(src.deltas_at(0)):
         for t in terms.tolist():
@@ -99,14 +103,14 @@ def build_collection(num_docs: int, vocab: int, seed: int):
     return [np.asarray(inv[t], np.int64) for t in sorted(inv)]
 
 
-def check_boolean(srv, lists, universe, queries, concurrency: int):
+def check_boolean(srv, lists, universe, queries):
     """Serve the workload through the scheduler; every answer must equal
     ``naive_eval`` over the raw lists.  Returns the answers."""
     import numpy as np
     from repro.query import naive_eval
     from repro.query.parser import parse
 
-    srv.scheduler.batch_window = concurrency
+    srv.scheduler.batch_window = CONCURRENCY
     outs = srv.search_many(queries)
     for q, got in zip(queries, outs):
         np.testing.assert_array_equal(
@@ -128,12 +132,12 @@ def one_chip(args, meter, lists, res) -> None:
     require(srv.engine.interpret is False, "pallas engine would interpret")
     print(f"stream: {srv.engine.pi.num_pages} pages of "
           f"{srv.engine.pi.page_size} symbols", flush=True)
-    queries = boolean_workload(lists, 4 * args.concurrency)
+    queries = boolean_workload(lists, 4 * CONCURRENCY)
 
     def pairs_phase():
         rng = np.random.default_rng(0)
         pairs = [tuple(map(int, rng.choice(len(lists), 2, replace=False)))
-                 for _ in range(args.pairs)]
+                 for _ in range(PAIRS)]
         outs = srv.and_batch(pairs)
         want = HostEngine(res).intersect_pairs(pairs)
         for (a, b), got, ref in zip(pairs, outs, want):
@@ -144,8 +148,7 @@ def one_chip(args, meter, lists, res) -> None:
                 f"host routes {srv.engine.host_routes}")
 
     def boolean_phase():
-        outs = check_boolean(srv, lists, res.universe, queries,
-                             args.concurrency)
+        outs = check_boolean(srv, lists, res.universe, queries)
         st = srv.serve_stats()
         return (f"{len(queries)} queries, {sum(map(len, outs))} hits, "
                 f"{st['dispatches']} merged dispatches, "
@@ -175,8 +178,7 @@ def one_chip(args, meter, lists, res) -> None:
                                codec=codec)
             require(csrv.engine.interpret is False,
                     "pallas engine would interpret")
-            check_boolean(csrv, lists, res.universe, queries,
-                          args.concurrency)
+            check_boolean(csrv, lists, res.universe, queries)
             n = LAUNCHES["ef_next_geq"] - before
             notes.append(f"{codec}: tiers {csrv.engine.tier.counts()}, "
                          f"{n} ef_next_geq launches, host routes "
@@ -187,7 +189,7 @@ def one_chip(args, meter, lists, res) -> None:
         return "; ".join(notes)
 
     def build_phase():
-        small = build_collection(args.build_docs, args.vocab, args.seed)
+        small = build_collection(BUILD_DOCS, args.seed)
         host = make_builder("host", table_cap=4096).build_grammar(small)
         bld = make_builder("pallas", table_cap=4096)
         require(bld.interpret is False, "pallas builder would interpret")
@@ -201,7 +203,7 @@ def one_chip(args, meter, lists, res) -> None:
         for field in ("seq", "starts", "first_values"):
             np.testing.assert_array_equal(getattr(dev, field),
                                           getattr(host, field))
-        return (f"{args.build_docs} docs, {dev.grammar.num_rules} rules "
+        return (f"{BUILD_DOCS} docs, {dev.grammar.num_rules} rules "
                 f"bit-identical to the host builder, pallas build "
                 f"{dt:.3f} s, {LAUNCHES['pair_count'] - before} "
                 f"pair_count launches")
@@ -227,7 +229,7 @@ def four_chips(args, meter, lists, res, devices) -> None:
     from repro.serve.query_serve import QueryServer
 
     mesh = Mesh(np.array(devices[:4]), ("data",))
-    queries = boolean_workload(lists, 4 * args.concurrency)
+    queries = boolean_workload(lists, 4 * CONCURRENCY)
 
     def sharded_phase():
         sharded = QueryServer(res, engine="pallas", max_short_len=256,
@@ -238,10 +240,8 @@ def four_chips(args, meter, lists, res, devices) -> None:
                     "pallas engine would interpret")
         require(sharded.engine._sharded_next_geq is not None,
                 "the mesh server has no sharded dispatch")
-        got = check_boolean(sharded, lists, res.universe, queries,
-                            args.concurrency)
-        ref = check_boolean(single, lists, res.universe, queries,
-                            args.concurrency)
+        got = check_boolean(sharded, lists, res.universe, queries)
+        ref = check_boolean(single, lists, res.universe, queries)
         for q, a, b in zip(queries, got, ref):
             np.testing.assert_array_equal(a, b, err_msg=q)
         return (f"{len(queries)} queries over a 4-chip data mesh equal "
@@ -255,11 +255,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     ap.add_argument("--num-docs", type=int, default=16000)
-    ap.add_argument("--vocab", type=int, default=4000)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--pairs", type=int, default=256)
-    ap.add_argument("--concurrency", type=int, default=64)
-    ap.add_argument("--build-docs", type=int, default=2000)
     args = ap.parse_args(argv)
 
     sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
@@ -283,7 +279,7 @@ def main(argv=None) -> int:
 
     from repro.build import make_builder
     t0 = time.perf_counter()
-    lists = build_collection(args.num_docs, args.vocab, args.seed)
+    lists = build_collection(args.num_docs, args.seed)
     res = make_builder("host").build_grammar(lists)
     setup = time.perf_counter() - t0
     print(f"collection: {args.num_docs} docs, {len(lists)} lists, "

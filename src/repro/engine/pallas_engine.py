@@ -70,18 +70,6 @@ class PallasEngine(DeviceEngine):
         m = max(1, int(self.score_index.max_page_elems))
         return max(128, 1 << (m - 1).bit_length())
 
-    def _score_page_size(self) -> int:
-        """The fused decode addresses a score entry by the stream page
-        that holds it, so directory pages must tile this engine's pages
-        (the launcher's fine 128-symbol directory does); any other cut is
-        refused here rather than served by a different decode."""
-        sp = super()._score_page_size()
-        if int(self.pi.page_size) % sp:
-            raise ValueError(
-                f"score directory page size {sp} does not divide the "
-                f"pallas engine's stream page size {self.pi.page_size}")
-        return sp
-
     def decode_page_batch(self, entries) -> np.ndarray:
         """Fused decode+score device path: page entries decode in one
         grid-blocked ``page_score`` pallas_call (one stream page DMA'd
@@ -89,10 +77,19 @@ class PallasEngine(DeviceEngine):
         the membership probes that score the fresh candidates then ride
         the fused ``list_intersect`` kernel, and the float32 reduction
         runs on device.  Out of core the full-stream operand pack does not
-        exist, and the windowed jnp decode reads the resident pool."""
+        exist, and the windowed jnp decode reads the resident pool.
+
+        The kernel addresses an entry by the stream page that holds it,
+        so the directory's pages (as built, after rounding) must tile this
+        engine's pages — the launcher's fine 128-symbol directory does.
+        Any other cut is refused rather than decoded by another path."""
         if self.resident is not None:
             return super().decode_page_batch(entries)
         si = self.score_index
+        if int(self.pi.page_size) % int(si.page_size):
+            raise ValueError(
+                f"score directory page size {si.page_size} does not divide "
+                f"the pallas engine's stream page size {self.pi.page_size}")
         if self._score_pack is None:
             self._score_pack = PS.pad_score_operands(self.pi)
         tables, statics = self._score_pack

@@ -63,10 +63,10 @@ def _paged_intersect_kernel(base_ref, slots_ref, lids_ref, xs_ref,
     k = pl.program_id(1)
     lid = lids_ref[...]                        # (1, TILE_Q) lane rows
     x = xs_ref[...]
-    end = table_gather(starts_ref, lid + 1)
 
     @pl.when(k == 0)
     def _init():
+        end = table_gather(starts_ref, lid + 1)
         pos = pos0_ref[...]
         s = s0_ref[...]
         last = table_gather(lasts_ref, lid)
@@ -89,6 +89,7 @@ def _paged_intersect_kernel(base_ref, slots_ref, lids_ref, xs_ref,
         # GLOBAL page id: offset math stays in stream coordinates;
         # slots_ref only steers which storage row the DMA reads
         cur = base_ref[i] + k
+        end = table_gather(starts_ref, lid + 1)
         pos = pos_sc[...]
         s = s_sc[...]
         done = done_sc[...] != 0

@@ -11,7 +11,9 @@ rebases them at the entry's window instead of running a cumsum, which
 Mosaic does not lower.
 
 ``page_decode(...)`` is the numpy-in/numpy-out launch the engine calls
-per ScoreRound.
+per ScoreRound.  It splits a round into launches of at most
+``ENTRIES_PER_LAUNCH`` entries, so the six scalar-prefetched per-entry
+arrays fit the chip's SMEM.
 """
 
 from __future__ import annotations
@@ -26,8 +28,12 @@ import jax.numpy as jnp
 from .. import count_launch, should_interpret
 from ...core.jax_index import PagedIndex
 from ..gather import pack_table
-from ..list_intersect.ops import paged_rows
+from ..list_intersect.ops import SMEM_BUDGET, paged_rows
 from .page_score import TILE_B, page_decode_pallas
+
+#: most entries (a power of two) whose six scalar-prefetched int32
+#: metadata arrays fit ``SMEM_BUDGET``
+ENTRIES_PER_LAUNCH = 1 << ((SMEM_BUDGET // (6 * 4)).bit_length() - 1)
 
 
 def pad_score_operands(pi: PagedIndex) -> tuple[tuple[jax.Array, ...], dict]:
@@ -78,10 +84,17 @@ def page_decode(tables: tuple[jax.Array, ...], statics: dict,
         interpret = should_interpret()
     if b_pad % TILE_B:
         raise ValueError(f"b_pad {b_pad} not a multiple of {TILE_B}")
-    count_launch("page_score", interpret)
-    out = _call(tables, jnp.asarray(pages, jnp.int32),
-                jnp.asarray(slo, jnp.int32), jnp.asarray(nsym, jnp.int32),
-                jnp.asarray(base, jnp.int32), jnp.asarray(head, jnp.int32),
-                jnp.asarray(cnt, jnp.int32),
-                b_pad=b_pad, interpret=bool(interpret), **statics)
-    return np.asarray(out)
+    meta = [np.asarray(a, np.int32) for a in (pages, slo, nsym, base, head,
+                                               cnt)]
+    # one launch per chunk of entries; every chunk is enqueued before any
+    # is read
+    outs = []
+    for t in range(0, meta[0].shape[0], ENTRIES_PER_LAUNCH):
+        count_launch("page_score", interpret)
+        outs.append(_call(
+            tables, *(jnp.asarray(a[t:t + ENTRIES_PER_LAUNCH])
+                      for a in meta),
+            b_pad=b_pad, interpret=bool(interpret), **statics))
+    if not outs:
+        return np.zeros((0, b_pad), np.int32)
+    return np.concatenate([np.asarray(o) for o in outs])

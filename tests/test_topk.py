@@ -339,11 +339,39 @@ def test_launcher_topk_runs_page_score(rlists, rres):
     assert LAUNCHES["page_score[interpret]"] > before
 
 
-def test_pallas_refuses_foreign_score_geometry(rres):
+@pytest.mark.parametrize("stream_page,score_page", [(256, 384), (384, 192)])
+def test_pallas_refuses_foreign_score_geometry(rres, stream_page,
+                                               score_page):
     """A score directory whose pages do not tile the pallas engine's stream
-    pages is refused, not decoded by another path."""
+    pages is refused, not decoded by another path.  The check reads the
+    directory as built: a requested 192 is cut at 256, which does not
+    divide 384 although 192 does."""
     eng = PallasEngine(rres, max_short_len=64, interpret=True,
-                       page_size=256)
-    eng.score_page_size = 384
+                       page_size=stream_page)
+    eng.score_page_size = score_page
     with pytest.raises(ValueError, match="does not divide"):
         search_topk(eng, [0, 1], 10)
+
+
+def test_page_decode_splits_large_rounds(monkeypatch):
+    """A ScoreRound larger than ``ENTRIES_PER_LAUNCH`` decodes in several
+    ``page_score`` launches, bit-identical to the host decode."""
+    from repro.kernels import LAUNCHES
+    from repro.kernels.page_score import ops as PS
+    lists = _skip_corpus()
+    res = repair_compress(lists)
+    si = build_score_index(res, page_size=128)
+    host = _make_engine("host", res)
+    host.score_page_size = 128
+    host.set_score_index(si)
+    eng = _make_engine("pallas", res)
+    eng.set_score_index(si)
+    entries = np.tile(np.arange(si.pg_list.size, dtype=np.int32), 3)
+    monkeypatch.setattr(PS, "ENTRIES_PER_LAUNCH", 16)
+    before = LAUNCHES["page_score[interpret]"]
+    got = eng.decode_page_batch(entries)
+    assert LAUNCHES["page_score[interpret]"] - before == -(-entries.size // 16)
+    want = host.decode_page_batch(entries)
+    w = min(got.shape[1], want.shape[1])
+    np.testing.assert_array_equal(got[:, :w], want[:, :w])
+    assert (got[:, w:] == np.iinfo(np.int32).max).all()

@@ -93,14 +93,23 @@ def test_list_intersect_compiles(i32, k_pages):
         k_pages=k_pages, interpret=False, **STATICS).compile())
 
 
-def test_page_score_compiles(i32):
-    entries, b_pad = 1024, 1024
+def _compile_page_score(i32, entries: int, b_pad: int = 1024) -> None:
     tables = (*(_table(i32, RULE_TABLE) for _ in range(4)),
               i32(PAGES, 1, PAGE), i32(PAGES, 1, PAGE), i32(PAGES, 1, PAGE))
     meta = [i32(entries) for _ in range(6)]
     _assert_kernel(PS._call.lower(
         tables, *meta, max_depth=STATICS["max_depth"], T=STATICS["T"],
         b_pad=b_pad, interpret=False).compile())
+
+
+def test_page_score_compiles(i32):
+    _compile_page_score(i32, 1024)
+
+
+def test_page_score_compiles_largest_launch(i32):
+    """The most entries ``page_decode`` puts in one launch: its six
+    scalar-prefetched metadata arrays at their SMEM budget."""
+    _compile_page_score(i32, PS.ENTRIES_PER_LAUNCH)
 
 
 def test_ef_next_geq_compiles(i32):
