@@ -47,6 +47,7 @@ from typing import Sequence
 
 import numpy as np
 
+from .. import obs
 from ..core.cache import LRUCache
 from ..core.jax_index import (DEFAULT_PAGE, INT_INF, ScoreIndex,
                               accumulate_scores, build_score_index)
@@ -339,49 +340,50 @@ class Engine(abc.ABC):
         Surviving unique lanes then consult the bounded probe memo; only
         memo misses reach the device.  A round fully served by the memo
         skips dispatch entirely."""
-        lids = np.asarray(list_ids, np.int32).ravel()
-        xq = np.asarray(xs, np.int32).ravel()
-        n = lids.size
-        if n == 0:
-            return np.empty(0, dtype=np.int32)
-        st = self.lane_stats
-        st["real_lanes"] += n
-        inv = None
-        if self.dedup and n > 1:
-            # (lid, x) -> one int64 key; bijective because list ids are
-            # non-negative int32 and x's 32 bits are masked in whole
-            key = ((lids.astype(np.int64) << 32)
-                   | (xq.astype(np.int64) & 0xFFFFFFFF))
-            _, uidx, inv = np.unique(key, return_index=True,
-                                     return_inverse=True)
-            if uidx.size == n:
-                inv = None           # nothing collapsed — skip the scatter
-            else:
-                lids, xq = lids[uidx], xq[uidx]
-        st["unique_lanes"] += lids.size
-        memo = self._probe_memo
-        if memo.maxsize > 0:
-            ver, ep = self.index_version, self.memo_epoch
-            out = np.empty(lids.size, np.int32)
-            lt, xt = lids.tolist(), xq.tolist()
-            miss = []
-            for j, (li, x) in enumerate(zip(lt, xt)):
-                v = memo.get((ver, ep, algo, li, x))
-                if v is None:
-                    miss.append(j)
+        with obs.span("engine.lanes"):
+            lids = np.asarray(list_ids, np.int32).ravel()
+            xq = np.asarray(xs, np.int32).ravel()
+            n = lids.size
+            if n == 0:
+                return np.empty(0, dtype=np.int32)
+            st = self.lane_stats
+            st["real_lanes"] += n
+            inv = None
+            if self.dedup and n > 1:
+                # (lid, x) -> one int64 key; bijective because list ids are
+                # non-negative int32 and x's 32 bits are masked in whole
+                key = ((lids.astype(np.int64) << 32)
+                       | (xq.astype(np.int64) & 0xFFFFFFFF))
+                _, uidx, inv = np.unique(key, return_index=True,
+                                         return_inverse=True)
+                if uidx.size == n:
+                    inv = None       # nothing collapsed — skip the scatter
                 else:
-                    out[j] = v
-            st["memo_hits"] += lids.size - len(miss)
-            st["memo_misses"] += len(miss)
-            if miss:
-                mi = np.asarray(miss, np.int64)
-                vals = self._dispatch_lanes(lids[mi], xq[mi], algo)
-                out[mi] = vals
-                for j, v in zip(miss, vals.tolist()):
-                    memo.put((ver, ep, algo, lt[j], xt[j]), int(v))
-        else:
-            out = self._dispatch_lanes(lids, xq, algo)
-        return out if inv is None else out[inv]
+                    lids, xq = lids[uidx], xq[uidx]
+            st["unique_lanes"] += lids.size
+            memo = self._probe_memo
+            if memo.maxsize > 0:
+                ver, ep = self.index_version, self.memo_epoch
+                out = np.empty(lids.size, np.int32)
+                lt, xt = lids.tolist(), xq.tolist()
+                miss = []
+                for j, (li, x) in enumerate(zip(lt, xt)):
+                    v = memo.get((ver, ep, algo, li, x))
+                    if v is None:
+                        miss.append(j)
+                    else:
+                        out[j] = v
+                st["memo_hits"] += lids.size - len(miss)
+                st["memo_misses"] += len(miss)
+                if miss:
+                    mi = np.asarray(miss, np.int64)
+                    vals = self._dispatch_lanes(lids[mi], xq[mi], algo)
+                    out[mi] = vals
+                    for j, v in zip(miss, vals.tolist()):
+                        memo.put((ver, ep, algo, lt[j], xt[j]), int(v))
+            else:
+                out = self._dispatch_lanes(lids, xq, algo)
+            return out if inv is None else out[inv]
 
     def _dispatch_lanes(self, lids: np.ndarray, xq: np.ndarray,
                         algo: str) -> np.ndarray:
@@ -531,27 +533,28 @@ class Engine(abc.ABC):
         the unique set, scatter rows back via the inverse map
         (DESIGN.md §13.1).  Page rows are too wide to memoize (the
         decode LRU already caches at whole-list granularity)."""
-        e = np.asarray(entries, np.int32).ravel()
-        n = e.size
-        if n == 0:
-            return np.empty((0, self.page_elem_bucket()), np.int32)
-        st = self.lane_stats
-        st["real_lanes"] += n
-        inv = None
-        if self.dedup and n > 1:
-            ue, inv = np.unique(e, return_inverse=True)
-            if ue.size == n:
-                inv = None
-            else:
-                e = ue.astype(np.int32)
-        st["unique_lanes"] += e.size
-        st["dispatched_lanes"] += e.size
-        self._in_round = True
-        try:
-            rows = self._dispatch_score_unique(e)
-        finally:
-            self._in_round = False
-        return rows if inv is None else rows[inv]
+        with obs.span("engine.lanes"):
+            e = np.asarray(entries, np.int32).ravel()
+            n = e.size
+            if n == 0:
+                return np.empty((0, self.page_elem_bucket()), np.int32)
+            st = self.lane_stats
+            st["real_lanes"] += n
+            inv = None
+            if self.dedup and n > 1:
+                ue, inv = np.unique(e, return_inverse=True)
+                if ue.size == n:
+                    inv = None
+                else:
+                    e = ue.astype(np.int32)
+            st["unique_lanes"] += e.size
+            st["dispatched_lanes"] += e.size
+            self._in_round = True
+            try:
+                rows = self._dispatch_score_unique(e)
+            finally:
+                self._in_round = False
+            return rows if inv is None else rows[inv]
 
     def _dispatch_score_unique(self, entries: np.ndarray) -> np.ndarray:
         """The post-dedup slice of a merged ScoreRound (host tier:

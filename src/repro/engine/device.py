@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import obs
 from ..core.jax_index import (FlatIndex, PagedIndex, as_store_backed,
                               build_flat_index, build_paged_index,
                               DEFAULT_PAGE)
@@ -45,6 +46,15 @@ from ..kernels.list_intersect import ops as K
 from .base import Engine
 from .host import HostEngine
 from . import jnp_backend as J
+
+
+def _pull(x) -> np.ndarray:
+    """``x`` as numpy; pulling a device array is the span ``device.wait``
+    (the host blocks there until the device has produced it)."""
+    if isinstance(x, jax.Array):
+        with obs.span("device.wait"):
+            return np.asarray(x)
+    return np.asarray(x)
 
 
 def shard_flat_index(fi: FlatIndex, num_shards: int
@@ -317,10 +327,10 @@ class DeviceEngine(Engine):
         lids = np.asarray(list_ids, np.int32)
         xq = np.asarray(xs, np.int32)
         if self._sharded_next_geq is not None:
-            return np.asarray(self._sharded_next_geq(lids, xq))
+            return _pull(self._sharded_next_geq(lids, xq))
         if self.resident is not None:
-            return np.asarray(self._next_geq_resident(lids, xq))
-        return np.asarray(self._next_geq_dev(lids, xq))
+            return _pull(self._next_geq_resident(lids, xq))
+        return _pull(self._next_geq_dev(lids, xq))
 
     def _next_geq_resident(self, lids: np.ndarray,
                            xq: np.ndarray) -> np.ndarray:
@@ -348,7 +358,7 @@ class DeviceEngine(Engine):
             return self._next_geq_repair(list_ids, xs)
         if self._bys_incl is None:
             self._bys_incl = J.build_bys_table(self.fi)
-        return np.asarray(J.next_geq_bys_batch(
+        return _pull(J.next_geq_bys_batch(
             self.fi, self._bys_incl, jnp.asarray(list_ids, jnp.int32),
             jnp.asarray(xs, jnp.int32)))
 
@@ -362,8 +372,7 @@ class DeviceEngine(Engine):
 
     def _ef_next_geq(self, lids, xq) -> np.ndarray:
         from ..core import ef as EF
-        return np.asarray(EF.ef_next_geq_jnp(self._ef_pack()["dev"],
-                                             lids, xq))
+        return _pull(EF.ef_next_geq_jnp(self._ef_pack()["dev"], lids, xq))
 
     def _bm_pack(self):
         key = (self.index_version, "bm")
@@ -376,8 +385,7 @@ class DeviceEngine(Engine):
 
     def _bitmap_next_geq(self, lids, xq) -> np.ndarray:
         from ..index import codec_tier as CT
-        return np.asarray(CT.bitmap_next_geq_jnp(self._bm_pack(),
-                                                 lids, xq))
+        return _pull(CT.bitmap_next_geq_jnp(self._bm_pack(), lids, xq))
 
     def _probe_tiered(self, long_ids, mat):
         """(B,) ids × (B, M) probes with per-list codec routing: repair
@@ -428,7 +436,7 @@ class DeviceEngine(Engine):
             return super()._decode_list(i)
         bucket = max(16, 1 << (max(1, n - 1)).bit_length())
         row = self._expand([i], bucket)
-        return self.compact(np.asarray(row[0]))
+        return self.compact(_pull(row[0]))
 
     def intersect_pairs(self, pairs: Sequence[tuple[int, int]]
                         ) -> list[np.ndarray]:
@@ -446,7 +454,7 @@ class DeviceEngine(Engine):
             mat = self._expand(shorts[dev], self.max_short_len)
             vals = self._probe_tiered(jnp.asarray(longs[dev], jnp.int32),
                                       mat)
-            kept = np.asarray(J.match_mask(vals, mat))
+            kept = _pull(J.match_mask(vals, mat))
             for qi, row in zip(dev, kept):
                 out[qi] = self.compact(row)
         host = np.flatnonzero(to_host)
@@ -474,7 +482,7 @@ class DeviceEngine(Engine):
         for i in order[1:]:
             vals = self._probe_tiered(jnp.asarray([i], jnp.int32), cand)
             cand = J.match_mask(vals, cand)
-        return self.compact(np.asarray(cand[0]))
+        return self.compact(_pull(cand[0]))
 
     # -- ranked scoring (DESIGN.md §9) --------------------------------------
 
@@ -530,7 +538,7 @@ class DeviceEngine(Engine):
                 jnp.asarray(si.pg_base[e], jnp.int32),
                 jnp.asarray(si.pg_head[e], jnp.int32),
                 win=int(si.page_size), max_elems=self.page_elem_bucket())
-            return np.asarray(out)
+            return _pull(out)
         out = J.decode_pages_batch(
             self.fi,
             jnp.asarray(si.pg_sym_lo[e], jnp.int32),
@@ -538,7 +546,7 @@ class DeviceEngine(Engine):
             jnp.asarray(si.pg_base[e], jnp.int32),
             jnp.asarray(si.pg_head[e], jnp.int32),
             win=int(si.page_size), max_elems=self.page_elem_bucket())
-        return np.asarray(out)
+        return _pull(out)
 
     def score_batch(self, doc_ids: np.ndarray, terms) -> np.ndarray:
         """Device-side score accumulation: the membership probes ride the
@@ -562,7 +570,7 @@ class DeviceEngine(Engine):
             jnp.asarray(si.idf[ts], jnp.float32),
             jnp.asarray(si.doc_w[docs], jnp.float32),
             jnp.asarray(member))
-        return np.asarray(out)
+        return _pull(out)
 
 
 class JnpEngine(DeviceEngine):

@@ -28,6 +28,7 @@ import numpy as np
 
 from ..core.jax_index import (FlatIndex, PagedIndex, build_paged_index,
                               DEFAULT_PAGE)
+from .. import obs
 from ..core.repair import RePairResult
 from ..kernels import should_interpret
 from ..kernels.list_intersect import ops as K
@@ -94,13 +95,15 @@ class PallasEngine(DeviceEngine):
             self._score_pack = PS.pad_score_operands(self.pi)
         tables, statics = self._score_pack
         e = np.asarray(entries, np.int64).ravel()
-        lo = si.pg_sym_lo[e].astype(np.int64)
-        pages = lo // int(self.pi.page_size)
-        return PS.page_decode(
-            tables, statics, pages, lo - pages * int(self.pi.page_size),
-            si.pg_sym_hi[e] - si.pg_sym_lo[e], si.pg_base[e],
-            si.pg_head[e], si.pg_count[e], b_pad=self.page_elem_bucket(),
-            interpret=self.interpret)
+        with obs.span("kernel.route"):
+            lo = si.pg_sym_lo[e].astype(np.int64)
+            pages = lo // int(self.pi.page_size)
+            meta = (pages, lo - pages * int(self.pi.page_size),
+                    si.pg_sym_hi[e] - si.pg_sym_lo[e], si.pg_base[e],
+                    si.pg_head[e], si.pg_count[e])
+        return PS.page_decode(tables, statics, *meta,
+                              b_pad=self.page_elem_bucket(),
+                              interpret=self.interpret)
 
     def _next_geq_dev(self, list_ids, xs) -> np.ndarray:
         return K.next_geq_paged(self._tables, self._host,

@@ -48,18 +48,43 @@ with the index are looked up one 128-lane row at a time
 (``kernels.gather``).  The other four have never been compiled for a chip.
 """
 
-import collections
+from collections.abc import Mapping
 
 import jax
 
-#: kernel launches in this process by kernel name, counted by the ops
-#: wrappers; a launch in interpret mode counts as ``"<name>[interpret]"``,
-#: so a run can show that each kernel ran, and ran compiled
-LAUNCHES: collections.Counter = collections.Counter()
+from .. import obs
+
+#: prefix of the recorder's launch counters (``launch.<kernel>``)
+LAUNCH_PREFIX = "launch."
+
+
+class _Launches(Mapping):
+    """Kernel launches in this process by kernel name: a view of the
+    recorder's ``launch.<name>`` counters (``repro.obs``), which the ops
+    wrappers add to.  A launch in interpret mode counts as
+    ``"<name>[interpret]"``, so a run can show that each kernel ran, and
+    ran compiled.  A name that never launched reads 0."""
+
+    def __getitem__(self, name: str) -> int:
+        return obs.counter(LAUNCH_PREFIX + name)
+
+    def __contains__(self, name) -> bool:
+        return self[name] > 0
+
+    def __iter__(self):
+        n = len(LAUNCH_PREFIX)
+        return iter([k[n:] for k in obs.RECORDER.counters(LAUNCH_PREFIX)])
+
+    def __len__(self) -> int:
+        return len(obs.RECORDER.counters(LAUNCH_PREFIX))
+
+
+LAUNCHES = _Launches()
 
 
 def count_launch(name: str, interpret: bool) -> None:
-    LAUNCHES[f"{name}[interpret]" if interpret else name] += 1
+    obs.count(f"{LAUNCH_PREFIX}{name}[interpret]" if interpret
+              else LAUNCH_PREFIX + name)
 
 
 def should_interpret() -> bool:

@@ -136,8 +136,10 @@ def ef_intersect_pallas(tile_base: jax.Array, done: jax.Array,
     (num_pages, 1, EF_PAGE) is the paged packed low-bits array.  Returns (Q,)
     int32 next_geq values, bit-exact vs ``core.ef.ef_next_geq_np``."""
     Q = done.shape[0]
-    kernel = lambda *refs: _ef_kernel(*refs, max_win=max_win,
-                                      k_pages=k_pages)
+    def ef_next_geq(*refs):
+        # Mosaic names the kernel after this function; the op in the
+        # trace keeps the jitted wrapper's name (``_ef_call``)
+        _ef_kernel(*refs, max_win=max_win, k_pages=k_pages)
     qspec = pl.BlockSpec((1, TILE_Q), lambda i, k, b: (0, i))
     pgspec = pl.BlockSpec((None, 1, EF_PAGE),
                           lambda i, k, b: (b[i] + k, 0, 0))
@@ -150,7 +152,7 @@ def ef_intersect_pallas(tile_base: jax.Array, done: jax.Array,
                         for _ in range(5)],
     )
     return pl.pallas_call(
-        kernel,
+        ef_next_geq,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, Q), jnp.int32),
         interpret=interpret,

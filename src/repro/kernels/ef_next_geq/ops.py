@@ -22,6 +22,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ... import obs
 from .. import count_launch
 from ...core.ef import EFStore, ef_probe_state_np
 from .ef_next_geq import EF_PAGE, TILE_Q, ef_intersect_pallas
@@ -125,13 +126,17 @@ def next_geq_ef(tables: jax.Array, statics: dict, store: EFStore,
     q = np.asarray(list_ids).shape[0]
     if q == 0:
         return np.zeros(0, np.int32)
-    order, base, k_pages, lanes = route_low_pages(
-        store, rank_pg, list_ids, xs, statics["num_pages"])
-    count_launch("ef_next_geq", interpret)
-    out = _ef_call(tables, jnp.asarray(base),
-                   *(jnp.asarray(lanes[k]) for k in _LANE_KEYS),
-                   max_win=statics["max_win"], k_pages=k_pages,
-                   interpret=interpret)
+    with obs.span("kernel.route"):
+        order, base, k_pages, lanes = route_low_pages(
+            store, rank_pg, list_ids, xs, statics["num_pages"])
+    with obs.span("kernel.launch"):
+        count_launch("ef_next_geq", interpret)
+        out = _ef_call(tables, jnp.asarray(base),
+                       *(jnp.asarray(lanes[k]) for k in _LANE_KEYS),
+                       max_win=statics["max_win"], k_pages=k_pages,
+                       interpret=interpret)
+    with obs.span("device.wait"):
+        out = np.asarray(out)
     unsort = np.empty(q, np.int64)
     unsort[order] = np.arange(q)
-    return np.asarray(out)[:q][unsort]
+    return out[:q][unsort]

@@ -181,9 +181,13 @@ def paged_intersect_pallas(tile_base: jax.Array, tile_slots: jax.Array,
     ``engine.jnp_backend.next_geq_batch_paged``."""
     Q = lids.shape[0]
     page = csyms_pg.shape[-1]
-    kernel = lambda *refs: _paged_intersect_kernel(
-        *refs, max_scan=max_scan, max_depth=max_depth, T=T, page=page,
-        k_pages=k_pages)
+    def list_intersect(*refs):
+        # Mosaic names the kernel after this function; a ``name=`` on the
+        # pallas_call would also rename the op the trace shows
+        # (``_paged_call``, the jitted wrapper's name)
+        _paged_intersect_kernel(*refs, max_scan=max_scan,
+                                max_depth=max_depth, T=T, page=page,
+                                k_pages=k_pages)
     qspec = pl.BlockSpec((1, TILE_Q), lambda i, k, b, sl: (0, i))
     tspec = lambda a: pl.BlockSpec(a.shape, lambda i, k, b, sl: (0, 0))
     pgspec = pl.BlockSpec((None, 1, page),
@@ -199,7 +203,7 @@ def paged_intersect_pallas(tile_base: jax.Array, tile_slots: jax.Array,
                         for _ in range(4)],
     )
     return pl.pallas_call(
-        kernel,
+        list_intersect,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, Q), jnp.int32),
         interpret=interpret,

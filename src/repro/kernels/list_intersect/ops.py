@@ -29,6 +29,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ... import obs
 from .. import count_launch, should_interpret
 from ...core.jax_index import (FlatIndex, PagedIndex, build_paged_index,
                                INT_INF)
@@ -218,31 +219,35 @@ def _launch_routed(tables, host, list_ids, xs, *, max_scan, max_depth, T,
     q = np.asarray(list_ids).shape[0]
     if q == 0:
         return np.zeros(0, np.int32)
-    order, base, k_pages, lids_s, xs_s, pos0_s, s0_s = route_pages(
-        host, list_ids, xs)
-    tile_pages = base[:, None].astype(np.int64) + np.arange(k_pages)
-    if resident is None:
-        tile_slots = tile_pages.astype(np.int32)
-    else:
-        resident.ensure(probe_working_set(host, list_ids, xs))
-        tile_slots = np.maximum(
-            resident.slot_of_page[tile_pages], 0).astype(np.int32)
-        csyms, csums, _ = resident.device_tables()
-        tables = tables[:5] + (paged_rows(csyms), paged_rows(csums))
+    with obs.span("kernel.route"):
+        order, base, k_pages, lids_s, xs_s, pos0_s, s0_s = route_pages(
+            host, list_ids, xs)
+        tile_pages = base[:, None].astype(np.int64) + np.arange(k_pages)
+        if resident is None:
+            tile_slots = tile_pages.astype(np.int32)
+        else:
+            resident.ensure(probe_working_set(host, list_ids, xs))
+            tile_slots = np.maximum(
+                resident.slot_of_page[tile_pages], 0).astype(np.int32)
+            csyms, csums, _ = resident.device_tables()
+            tables = tables[:5] + (paged_rows(csyms), paged_rows(csums))
     # one launch per chunk of tiles, so the scalar-prefetched page table
     # fits the chip's SMEM; every chunk is enqueued before any is read
     step = tiles_per_launch(k_pages)
     outs = []
-    for t in range(0, base.shape[0], step):
-        lanes = slice(t * TILE_Q, (t + step) * TILE_Q)
-        count_launch("list_intersect", interpret)
-        outs.append(_paged_call(
-            tables, jnp.asarray(base[t:t + step]),
-            jnp.asarray(tile_slots[t:t + step]), jnp.asarray(lids_s[lanes]),
-            jnp.asarray(xs_s[lanes]), jnp.asarray(pos0_s[lanes]),
-            jnp.asarray(s0_s[lanes]), max_scan=max_scan,
-            max_depth=max_depth, T=T, k_pages=k_pages, interpret=interpret))
-    out = np.concatenate([np.asarray(o) for o in outs])
+    with obs.span("kernel.launch"):
+        for t in range(0, base.shape[0], step):
+            lanes = slice(t * TILE_Q, (t + step) * TILE_Q)
+            count_launch("list_intersect", interpret)
+            outs.append(_paged_call(
+                tables, jnp.asarray(base[t:t + step]),
+                jnp.asarray(tile_slots[t:t + step]),
+                jnp.asarray(lids_s[lanes]), jnp.asarray(xs_s[lanes]),
+                jnp.asarray(pos0_s[lanes]), jnp.asarray(s0_s[lanes]),
+                max_scan=max_scan, max_depth=max_depth, T=T,
+                k_pages=k_pages, interpret=interpret))
+    with obs.span("device.wait"):
+        out = np.concatenate([np.asarray(o) for o in outs])
     unsort = np.empty(q, np.int64)
     unsort[order] = np.arange(q)
     return out[:q][unsort]

@@ -25,6 +25,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ... import obs
 from .. import count_launch, should_interpret
 from ...core.jax_index import PagedIndex
 from ..gather import pack_table
@@ -89,12 +90,14 @@ def page_decode(tables: tuple[jax.Array, ...], statics: dict,
     # one launch per chunk of entries; every chunk is enqueued before any
     # is read
     outs = []
-    for t in range(0, meta[0].shape[0], ENTRIES_PER_LAUNCH):
-        count_launch("page_score", interpret)
-        outs.append(_call(
-            tables, *(jnp.asarray(a[t:t + ENTRIES_PER_LAUNCH])
-                      for a in meta),
-            b_pad=b_pad, interpret=bool(interpret), **statics))
+    with obs.span("kernel.launch"):
+        for t in range(0, meta[0].shape[0], ENTRIES_PER_LAUNCH):
+            count_launch("page_score", interpret)
+            outs.append(_call(
+                tables, *(jnp.asarray(a[t:t + ENTRIES_PER_LAUNCH])
+                          for a in meta),
+                b_pad=b_pad, interpret=bool(interpret), **statics))
     if not outs:
         return np.zeros((0, b_pad), np.int32)
-    return np.concatenate([np.asarray(o) for o in outs])
+    with obs.span("device.wait"):
+        return np.concatenate([np.asarray(o) for o in outs])

@@ -30,7 +30,7 @@ TILE_N = 512   # sequence slots per instance (lane multiple)
 TILE_K = 512   # candidate pairs per instance
 
 
-def _pair_count_kernel(a_ref, b_ref, pa_ref, pb_ref, vm_ref, out_ref):
+def pair_count(a_ref, b_ref, pa_ref, pb_ref, vm_ref, out_ref):
     t = pl.program_id(1)
 
     @pl.when(t == 0)
@@ -71,7 +71,7 @@ def pair_count_pallas(cand_a: jax.Array, cand_b: jax.Array,
     cspec = pl.BlockSpec((1, tk), lambda kt, t: (0, kt))
     sspec = pl.BlockSpec((None, 1, tn), lambda kt, t: (t, 0, 0))
     return pl.pallas_call(
-        _pair_count_kernel,
+        pair_count,                      # Mosaic's name for the kernel
         grid=(kp // tk, nt),
         in_specs=[cspec, cspec, sspec, sspec, sspec],
         out_specs=pl.BlockSpec((1, tk), lambda kt, t: (0, kt)),

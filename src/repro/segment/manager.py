@@ -36,10 +36,10 @@ from __future__ import annotations
 
 import dataclasses
 import os
-import time
 
 import numpy as np
 
+from .. import obs
 from ..core.jax_index import (bm25_doc_weights, bm25_idf, build_score_index)
 from ..core.repair import RePairResult
 from ..query import QueryExecutor
@@ -366,23 +366,24 @@ class SegmentedIndex:
         n = self.delta_docs
         if n == 0:
             return None
-        t0 = time.perf_counter()
-        base = self._base0 + self.cursor
-        inv: dict[int, list[int]] = {}
-        for j, terms in enumerate(self._log[self.cursor:]):
-            for t in terms.tolist():
-                inv.setdefault(int(t), []).append(j)
-        dl_local = np.asarray([int(t.size) for t in
-                               self._log[self.cursor:]], np.int64)
-        lists_by_term = {t: np.asarray(d, np.int64) for t, d in inv.items()}
-        seg = self._build_segment(base, n, lists_by_term, gen=0,
-                                  dl_local=dl_local)
-        # atomic commit
-        self.segments = self.segments + (seg,)
-        self.cursor = len(self._log)
-        self._delta_inv = {}
-        self.flushes += 1
-        self.flush_ms += (time.perf_counter() - t0) * 1e3
+        with obs.span("segment.flush") as span:
+            base = self._base0 + self.cursor
+            inv: dict[int, list[int]] = {}
+            for j, terms in enumerate(self._log[self.cursor:]):
+                for t in terms.tolist():
+                    inv.setdefault(int(t), []).append(j)
+            dl_local = np.asarray([int(t.size) for t in
+                                   self._log[self.cursor:]], np.int64)
+            lists_by_term = {t: np.asarray(d, np.int64)
+                             for t, d in inv.items()}
+            seg = self._build_segment(base, n, lists_by_term, gen=0,
+                                      dl_local=dl_local)
+            # atomic commit
+            self.segments = self.segments + (seg,)
+            self.cursor = len(self._log)
+            self._delta_inv = {}
+            self.flushes += 1
+        self.flush_ms += span.seconds * 1e3
         return seg
 
     def _build_segment(self, base: int, n: int,

@@ -57,6 +57,7 @@ from typing import Sequence
 
 import numpy as np
 
+from .. import obs
 from ..core.cache import LRUCache
 from ..engine.base import _env_flag
 from ..query import QueryExecutor
@@ -164,6 +165,7 @@ class QueryScheduler:
         self.prefetched_pages = 0
         self.prefetch_useful = 0
         self._completed = 0
+        self._ticks = 0
         self.failures = 0
         # ranked-retrieval counters (cumulative; survive hot swaps so a
         # long-lived server's pruning efficacy is observable end to end)
@@ -228,34 +230,36 @@ class QueryScheduler:
         qid = self._next_qid
         self._next_qid += 1
         t0 = time.perf_counter()
-        if self.segmented is not None:
-            # segmented mode: the machine snapshots delta + segments at
-            # submit; the key folds in the CONTENT epoch (one per insert —
-            # flush/compaction reorganize without changing answers, so
-            # cached results survive them)
-            node = parse(q, None) if isinstance(q, str) else q
-            key = (self._version, "bool-seg", self.segmented.epoch,
-                   force_algo, node)
+        with obs.span("sched.submit", qid=qid):
+            if self.segmented is not None:
+                # segmented mode: the machine snapshots delta + segments
+                # at submit; the key folds in the CONTENT epoch (one per
+                # insert — flush/compaction reorganize without changing
+                # answers, so cached results survive them)
+                node = parse(q, None) if isinstance(q, str) else q
+                key = (self._version, "bool-seg", self.segmented.epoch,
+                       force_algo, node)
+                hit = self.result_cache.get(key)
+                if hit is not None:
+                    self._finish(qid, hit.copy(), t0)
+                    return qid
+                fl = _InFlight(qid,
+                               self.segmented.lower_bool(node, force_algo),
+                               self._engine, self._version, key, t0,
+                               terms=terms_of(node))
+                self._queue.append(fl)
+                return fl.qid
+            ex = self._executor(force_algo)
+            node = parse(q, ex.term_map) if isinstance(q, str) else q
+            key = (self._version, "bool", force_algo, node)
             hit = self.result_cache.get(key)
             if hit is not None:
                 self._finish(qid, hit.copy(), t0)
                 return qid
-            fl = _InFlight(qid, self.segmented.lower_bool(node, force_algo),
-                           self._engine, self._version, key, t0,
-                           terms=terms_of(node))
+            fl = _InFlight(qid, ex.lower(ex.plan(node)), self._engine,
+                           self._version, key, t0, terms=terms_of(node))
             self._queue.append(fl)
             return fl.qid
-        ex = self._executor(force_algo)
-        node = parse(q, ex.term_map) if isinstance(q, str) else q
-        key = (self._version, "bool", force_algo, node)
-        hit = self.result_cache.get(key)
-        if hit is not None:
-            self._finish(qid, hit.copy(), t0)
-            return qid
-        fl = _InFlight(qid, ex.lower(ex.plan(node)), self._engine,
-                       self._version, key, t0, terms=terms_of(node))
-        self._queue.append(fl)
-        return fl.qid
 
     def submit_topk(self, q, k: int = 10, *, prune: bool = True) -> int:
         """Enqueue one ranked top-k query (a term bag — a query string,
@@ -267,35 +271,36 @@ class QueryScheduler:
         qid = self._next_qid
         self._next_qid += 1
         t0 = time.perf_counter()
-        if self.segmented is not None:
-            terms = tuple(sorted({int(t) for t in _term_bag(q)
-                                  if 0 <= int(t)
-                                  < self.segmented.num_terms}))
-            key = (self._version, "topk-seg", self.segmented.epoch,
-                   terms, int(k), bool(prune))
+        with obs.span("sched.submit", qid=qid):
+            if self.segmented is not None:
+                terms = tuple(sorted({int(t) for t in _term_bag(q)
+                                      if 0 <= int(t)
+                                      < self.segmented.num_terms}))
+                key = (self._version, "topk-seg", self.segmented.epoch,
+                       terms, int(k), bool(prune))
+                hit = self.result_cache.get(key)
+                if hit is not None:
+                    self._finish(qid, hit.copy(), t0)
+                    return qid
+                fl = _InFlight(qid,
+                               self.segmented.lower_topk(terms, int(k),
+                                                         prune=prune),
+                               self._engine, self._version, key, t0,
+                               terms=list(terms))
+                self._queue.append(fl)
+                return fl.qid
+            terms = tuple(self._executor(None).query_terms(q))
+            key = (self._version, "topk", terms, int(k), bool(prune))
             hit = self.result_cache.get(key)
             if hit is not None:
                 self._finish(qid, hit.copy(), t0)
                 return qid
-            fl = _InFlight(qid,
-                           self.segmented.lower_topk(terms, int(k),
-                                                     prune=prune),
+            fl = _InFlight(qid, lower_topk(self._engine.score_index, terms,
+                                           int(k), prune=prune),
                            self._engine, self._version, key, t0,
                            terms=list(terms))
             self._queue.append(fl)
             return fl.qid
-        terms = tuple(self._executor(None).query_terms(q))
-        key = (self._version, "topk", terms, int(k), bool(prune))
-        hit = self.result_cache.get(key)
-        if hit is not None:
-            self._finish(qid, hit.copy(), t0)
-            return qid
-        fl = _InFlight(qid, lower_topk(self._engine.score_index, terms,
-                                       int(k), prune=prune),
-                       self._engine, self._version, key, t0,
-                       terms=list(terms))
-        self._queue.append(fl)
-        return fl.qid
 
     def take(self, qid: int) -> np.ndarray:
         """Pop a completed query's result (KeyError if not done yet)."""
@@ -313,7 +318,13 @@ class QueryScheduler:
         gather on a background thread, double-buffered against this
         tick's dispatches (DESIGN.md §13.3).  The thread is joined — and
         its pages admitted — at the top of the next tick, before any
-        code touches the resident pools."""
+        code touches the resident pools.  The tick is the span
+        ``sched.tick`` (metadata: its number)."""
+        self._ticks += 1
+        with obs.span("sched.tick", tick=self._ticks):
+            return self._tick()
+
+    def _tick(self) -> int:
         self._join_prefetch()
         while self._queue and len(self._running) < self.batch_window:
             fl = self._queue.popleft()
@@ -369,13 +380,17 @@ class QueryScheduler:
             if gkey[1] == "score":      # merged ranked page decode
                 entries = np.concatenate([r.entries for r in rounds])
                 self._merged_lanes += int(entries.size)
-                vals = np.asarray(eng.dispatch_score_round(entries))
+                with obs.span("sched.dispatch", algo="score",
+                              queries=len(fls), lanes=int(entries.size)):
+                    vals = np.asarray(eng.dispatch_score_round(entries))
             else:
                 algo = gkey[2]
                 lids = np.concatenate([r.list_ids for r in rounds])
                 xs = np.concatenate([r.xs for r in rounds])
                 self._merged_lanes += int(lids.size)
-                vals = np.asarray(eng.dispatch_round(lids, xs, algo))
+                with obs.span("sched.dispatch", algo=algo,
+                              queries=len(fls), lanes=int(lids.size)):
+                    vals = np.asarray(eng.dispatch_round(lids, xs, algo))
             for k in _LANE_KEYS:
                 self._lane_totals[k] += eng.lane_stats[k] - lane_snap[k]
             off = 0
@@ -458,10 +473,10 @@ class QueryScheduler:
         self._pf_results = [None] * len(jobs)
 
         def _gather(jobs=jobs, out=self._pf_results):
-            t0 = time.perf_counter()
-            for i, (res, pages) in enumerate(jobs):
-                out[i] = res.store.gather(pages)
-            self._pf_gather_s = time.perf_counter() - t0
+            with obs.span("store.prefetch_gather") as gather:
+                for i, (res, pages) in enumerate(jobs):
+                    out[i] = res.store.gather(pages)
+            self._pf_gather_s = gather.seconds
 
         self._pf_thread = threading.Thread(target=_gather, daemon=True,
                                            name="repro-prefetch")
@@ -473,9 +488,9 @@ class QueryScheduler:
         the main thread, always before the tick touches any slot."""
         if self._pf_thread is None:
             return
-        t0 = time.perf_counter()
-        self._pf_thread.join()
-        waited = time.perf_counter() - t0
+        with obs.span("sched.prefetch_join") as join:
+            self._pf_thread.join()
+        waited = join.seconds
         self._pf_thread = None
         gathered = self._pf_gather_s
         self.prefetch_gather_ms += gathered * 1e3
@@ -498,47 +513,48 @@ class QueryScheduler:
         machine that RAISES is retired before the error propagates — a
         poisoned query must not wedge the scheduler: everything else in
         flight keeps ticking on the next call."""
-        try:
-            step = next(fl.machine) if start else fl.machine.send(value)
-            while True:
-                if isinstance(step, (ProbeRound, ScoreRound)):
-                    fl.pending = step
+        with obs.span("sched.advance", qid=fl.qid):
+            try:
+                step = next(fl.machine) if start else fl.machine.send(value)
+                while True:
+                    if isinstance(step, (ProbeRound, ScoreRound)):
+                        fl.pending = step
+                        return
+                    if isinstance(step, DecodeList):
+                        res = self._decode(fl, step.t)
+                    else:                   # SetOp / PhraseShift: pure host
+                        res = step.run()
+                    step = fl.machine.send(res)
+            except StopIteration as stop:
+                fl.done = True
+                if isinstance(stop.value, RankedResult):
+                    rr: RankedResult = stop.value
+                    self.pages_scored += rr.pages_scored
+                    self.pages_skipped += rr.pages_skipped
+                    if rr.threshold > float("-inf"):
+                        self.threshold_final = float(rr.threshold)
+                    if fl.key is not None and self.result_cache.maxsize > 0:
+                        cached = rr.copy()
+                        cached.docs.flags.writeable = False
+                        cached.scores.flags.writeable = False
+                        self.result_cache.put(fl.key, cached)
+                    self._finish(fl.qid, rr, fl.t0, fl.rounds)
                     return
-                if isinstance(step, DecodeList):
-                    res = self._decode(fl, step.t)
-                else:                   # SetOp / PhraseShift: pure host
-                    res = step.run()
-                step = fl.machine.send(res)
-        except StopIteration as stop:
-            fl.done = True
-            if isinstance(stop.value, RankedResult):
-                rr: RankedResult = stop.value
-                self.pages_scored += rr.pages_scored
-                self.pages_skipped += rr.pages_skipped
-                if rr.threshold > float("-inf"):
-                    self.threshold_final = float(rr.threshold)
+                out = np.asarray(stop.value, dtype=np.int64)
+                out = out if out.flags.writeable else out.copy()
                 if fl.key is not None and self.result_cache.maxsize > 0:
-                    cached = rr.copy()
-                    cached.docs.flags.writeable = False
-                    cached.scores.flags.writeable = False
+                    cached = out.copy()
+                    cached.flags.writeable = False
                     self.result_cache.put(fl.key, cached)
-                self._finish(fl.qid, rr, fl.t0)
-                return
-            out = np.asarray(stop.value, dtype=np.int64)
-            out = out if out.flags.writeable else out.copy()
-            if fl.key is not None and self.result_cache.maxsize > 0:
-                cached = out.copy()
-                cached.flags.writeable = False
-                self.result_cache.put(fl.key, cached)
-            self._finish(fl.qid, out, fl.t0)
-        except BaseException:
-            # retire the poisoned query so the next tick filters it out
-            # of _running instead of spinning on pending=None forever;
-            # the error still reaches the caller (drain/search_many)
-            fl.done = True
-            self.failures += 1
-            fl.machine.close()
-            raise
+                self._finish(fl.qid, out, fl.t0, fl.rounds)
+            except BaseException:
+                # retire the poisoned query so the next tick filters it out
+                # of _running instead of spinning on pending=None forever;
+                # the error still reaches the caller (drain/search_many)
+                fl.done = True
+                self.failures += 1
+                fl.machine.close()
+                raise
 
     def _decode(self, fl: _InFlight, t: int) -> np.ndarray:
         """Serve a DecodeList step.  Deliberately two cache layers: this
@@ -554,7 +570,11 @@ class QueryScheduler:
             self.decode_cache.put(key, arr)
         return arr
 
-    def _finish(self, qid: int, out: np.ndarray, t0: float) -> None:
+    def _finish(self, qid: int, out: np.ndarray, t0: float,
+                rounds: int = 0) -> None:
+        """Complete a query that took ``rounds`` merged dispatches (0 for
+        a result-cache hit); the counter ``sched.rounds`` adds them."""
+        obs.count("sched.rounds", rounds)
         self._done[qid] = out
         self.completion_order.append(qid)
         now = time.perf_counter()
@@ -707,6 +727,9 @@ class QueryScheduler:
             # out-of-core admission cache (DESIGN.md §11.5): zeros when
             # the live engine serves fully resident
             **self._store_stats(),
+            # the process's span-and-counter table (``repro.obs``):
+            # readers take deltas of two of these
+            "spans": obs.snapshot(),
             # streaming-ingestion telemetry (DESIGN.md §12): zeros when no
             # segmented manager is attached
             **(self.segmented.telemetry() if self.segmented is not None
