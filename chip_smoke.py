@@ -23,7 +23,9 @@ Every answer of every phase is compared with a plain reference
 boolean queries, ``rank_oracle`` for top-k).  The run fails when JAX finds
 no TPU, when any answer differs, when a pallas engine or builder would
 interpret its kernels, or when one of ``list_intersect``, ``page_score``,
-``ef_next_geq`` and ``pair_count`` did not launch compiled.  With
+``ef_next_geq`` and ``pair_count`` did not launch compiled, or when a
+launch of ``list_intersect`` or ``page_score`` did not count its table
+lookups on the MXU (``gather.mxu.<kernel>``).  With
 ``--chips 4`` the run serves the boolean workload from
 ``QueryServer(mesh=...)`` over four chips and compares it with the
 one-chip engine and the oracle.  Each phase prints its wall time and
@@ -40,6 +42,7 @@ import time
 from pathlib import Path
 
 KERNELS = ("list_intersect", "page_score", "ef_next_geq", "pair_count")
+MXU_KERNELS = ("list_intersect", "page_score")   # table lookups on the MXU
 VOCAB = 4000          # PostingsSource vocabulary
 PAIRS = 256           # conjunctive pairs in the pairs phase
 CONCURRENCY = 64      # scheduler batch window of the boolean phases
@@ -122,7 +125,8 @@ def one_chip(args, meter, lists, res) -> None:
     import numpy as np
     from repro.build import make_builder
     from repro.engine import HostEngine
-    from repro.kernels import LAUNCHES
+    from repro import obs
+    from repro.kernels import LAUNCHES, MXU_PREFIX
     from repro.launch.serve import (SCORE_PAGE, boolean_workload,
                                     ranked_workload)
     from repro.query import rank_oracle
@@ -220,6 +224,10 @@ def one_chip(args, meter, lists, res) -> None:
     require(not missing and not interpreted,
             f"kernels not launched compiled: {missing}; interpreted: "
             f"{interpreted}")
+    mxu = {k: obs.counter(MXU_PREFIX + k) for k in MXU_KERNELS}
+    print(f"launches with MXU table lookups: {mxu}", flush=True)
+    wrong = {k: n for k, n in mxu.items() if n != LAUNCHES[k]}
+    require(not wrong, f"launches with MXU lookups differ: {wrong}")
 
 
 def four_chips(args, meter, lists, res, devices) -> None:

@@ -44,8 +44,11 @@ four served kernels (``list_intersect``, ``page_score``, ``ef_next_geq``,
 ``tests/test_tpu_compile.py``: every block's last two dims are (8, 128)
 multiples or equal to the array's (paged streams are laid out
 ``(num_pages, 1, PAGE)`` with the page dim squeezed), and tables that grow
-with the index are looked up one 128-lane row at a time
-(``kernels.gather``).  The other four have never been compiled for a chip.
+with the index are looked up on the MXU: a row one-hot of at most 512
+rows times the table's four byte planes, exact in int32
+(``kernels.gather``), so no intermediate grows with the rule count.  A
+launch whose lookups ran there counts ``gather.mxu.<kernel>`` beside
+``launch.<kernel>``.  The other four have never been compiled for a chip.
 """
 
 from collections.abc import Mapping
@@ -56,6 +59,9 @@ from .. import obs
 
 #: prefix of the recorder's launch counters (``launch.<kernel>``)
 LAUNCH_PREFIX = "launch."
+#: prefix of the counters of launches whose table lookups ran on the MXU
+#: (``gather.mxu.<kernel>``, ``kernels.gather.table_gather``)
+MXU_PREFIX = "gather.mxu."
 
 
 class _Launches(Mapping):
@@ -82,9 +88,13 @@ class _Launches(Mapping):
 LAUNCHES = _Launches()
 
 
-def count_launch(name: str, interpret: bool) -> None:
-    obs.count(f"{LAUNCH_PREFIX}{name}[interpret]" if interpret
-              else LAUNCH_PREFIX + name)
+def count_launch(name: str, interpret: bool, mxu: bool = False) -> None:
+    """One launch of kernel ``name``; ``mxu`` when its table lookups run
+    on the MXU.  Interpret-mode launches count as ``<name>[interpret]``."""
+    name += "[interpret]" if interpret else ""
+    obs.count(LAUNCH_PREFIX + name)
+    if mxu:
+        obs.count(MXU_PREFIX + name)
 
 
 def should_interpret() -> bool:

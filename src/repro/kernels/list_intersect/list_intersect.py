@@ -31,10 +31,11 @@ innermost, so scratch written at step (i, k) is live at (i, k+1)):
      whole (the paper's "dictionary fits in RAM", one level down) since
      they are O(S), not O(N).
 
-Table lookups are masked-sum one-hot gathers (``kernels.gather``): the
-resident page with one (TILE_Q, PAGE) compare, the list and grammar
-tables one 128-lane row at a time, so VMEM stays bounded however many
-rules the grammar has.  The stream arrives as ``(num_pages, 1, PAGE)`` so
+Table lookups (``kernels.gather``): the resident page with one
+(TILE_Q, PAGE) one-hot compare on the VPU; the list and grammar tables on
+the MXU, a (TILE_Q, rows) row one-hot times the table's byte planes, the
+planes of the descent's tables built once per step and ``sym_left`` /
+``sym_right`` read by one dot.  The stream arrives as ``(num_pages, 1, PAGE)`` so
 each page block's last two dims equal the array's (the TPU block-shape
 rule); the leading page dim is squeezed away inside the kernel.
 """
@@ -46,7 +47,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..gather import row_gather, table_gather
+from ..gather import plane_gather, row_gather, table_gather, table_planes
 
 TILE_Q = 128
 INT_INF = 2**31 - 1  # plain int: jnp array constants can't be captured
@@ -125,13 +126,16 @@ def _paged_intersect_kernel(base_ref, slots_ref, lids_ref, xs_ref,
 
         # -- fixed-depth grammar descent inside the resident page ----------
         sym0 = row_gather(csyms, jnp.where(in_page, off, -1))
+        kids = table_planes(sleft_ref, sright_ref)
+        sums = table_planes(ssum_ref)
 
         def descend_body(_, state):
             sym, s = state
             is_rule = sym >= T
-            l = jnp.where(is_rule, table_gather(sleft_ref, sym), sym)
-            r = jnp.where(is_rule, table_gather(sright_ref, sym), sym)
-            ls = table_gather(ssum_ref, l)
+            left, right = plane_gather(kids, sym)
+            l = jnp.where(is_rule, left, sym)
+            r = jnp.where(is_rule, right, sym)
+            (ls,) = plane_gather(sums, l)
             go_left = s + ls >= x
             new_sym = jnp.where(go_left, l, r)
             new_s = jnp.where(go_left, s, s + ls)
@@ -140,7 +144,7 @@ def _paged_intersect_kernel(base_ref, slots_ref, lids_ref, xs_ref,
 
         sym_f, s_f = jax.lax.fori_loop(0, max_depth, descend_body,
                                        (sym0, s))
-        answer = s_f + table_gather(ssum_ref, sym_f)
+        answer = s_f + plane_gather(sums, sym_f)[0]
 
         val = jnp.where(done_early, s, answer)
         val = jnp.where(past_end & ~done_early, INT_INF, val)

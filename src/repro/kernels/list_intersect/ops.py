@@ -238,7 +238,7 @@ def _launch_routed(tables, host, list_ids, xs, *, max_scan, max_depth, T,
     with obs.span("kernel.launch"):
         for t in range(0, base.shape[0], step):
             lanes = slice(t * TILE_Q, (t + step) * TILE_Q)
-            count_launch("list_intersect", interpret)
+            count_launch("list_intersect", interpret, mxu=True)
             outs.append(_paged_call(
                 tables, jnp.asarray(base[t:t + step]),
                 jnp.asarray(tile_slots[t:t + step]),

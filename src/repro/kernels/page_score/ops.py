@@ -92,7 +92,7 @@ def page_decode(tables: tuple[jax.Array, ...], statics: dict,
     outs = []
     with obs.span("kernel.launch"):
         for t in range(0, meta[0].shape[0], ENTRIES_PER_LAUNCH):
-            count_launch("page_score", interpret)
+            count_launch("page_score", interpret, mxu=True)
             outs.append(_call(
                 tables, *(jnp.asarray(a[t:t + ENTRIES_PER_LAUNCH])
                           for a in meta),

@@ -24,8 +24,11 @@ absolute value after each symbol of the entry's window come from the
 page's prefix sums rebased at the window start (Mosaic has no cumsum;
 int32 wraparound cancels in the difference), a compare-count
 ``searchsorted`` locates each output slot's owning symbol, then the
-fixed-depth positional descent with per-node length counters.  All
-gathers are one-hot masked sums (``kernels.gather``, exact in int32).
+fixed-depth positional descent with per-node length counters.  Page
+gathers are one-hot masked sums on the VPU; grammar lookups run on the
+MXU (``kernels.gather``, exact in int32), with each level's two pairs of
+same-index lookups (``sym_left``/``sym_right``, ``sym_len``/``sym_sum``)
+one dot each.
 """
 
 from __future__ import annotations
@@ -35,7 +38,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..gather import row_gather, table_gather
+from ..gather import plane_gather, row_gather, table_planes
 
 TILE_B = 128
 INT_INF = 2**31 - 1  # plain int: jnp array constants can't be captured
@@ -92,16 +95,19 @@ def _page_decode_tile(tb, off0, n, base, head, sleft_ref, sright_ref,
     base_s = jnp.where(k > 0, row_gather(cum_sum, k - 1), base)
     base_t = jnp.where(k > 0, row_gather(cum_len, k - 1), 0)
     sym0 = row_gather(syms, k)
+    kids = table_planes(sleft_ref, sright_ref)
+    lens_sums = table_planes(slen_ref, ssum_ref)
 
     def body(_, state):
         sym, s, wrem = state
         is_rule = sym >= T
-        l = jnp.where(is_rule, table_gather(sleft_ref, sym), sym)
-        r = jnp.where(is_rule, table_gather(sright_ref, sym), sym)
-        ll = table_gather(slen_ref, l)
+        left, right = plane_gather(kids, sym)
+        l = jnp.where(is_rule, left, sym)
+        r = jnp.where(is_rule, right, sym)
+        ll, ls = plane_gather(lens_sums, l)
         go_left = wrem <= ll
         nsym = jnp.where(go_left, l, r)
-        ns = jnp.where(go_left, s, s + table_gather(ssum_ref, l))
+        ns = jnp.where(go_left, s, s + ls)
         nw = jnp.where(go_left, wrem, wrem - ll)
         return (jnp.where(is_rule, nsym, sym),
                 jnp.where(is_rule, ns, s),
@@ -109,7 +115,7 @@ def _page_decode_tile(tb, off0, n, base, head, sleft_ref, sright_ref,
 
     symf, sf, _ = jax.lax.fori_loop(0, max_depth, body,
                                     (sym0, base_s, w - base_t))
-    vals = sf + table_gather(ssum_ref, symf)
+    vals = sf + plane_gather(lens_sums, symf)[1]
     out = jnp.where(want < 1, base, vals)
     out_ref[...] = jnp.where(j < total, out, INT_INF).astype(jnp.int32)
 

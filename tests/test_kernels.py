@@ -145,6 +145,35 @@ def test_list_intersect_probe_matrix(lists, li_flat, rng):
             np.unique(kept), np.intersect1d(probes, lists[long_ids[r]]))
 
 
+# -- table_gather (in-kernel lookups on the MXU) ------------------------------------
+
+@pytest.mark.parametrize("rows", [1, 7, 128, 148, 227, 482, 512, 1100])
+def test_table_gather_exact(rows):
+    """One lookup per lane of a ``pack_table`` table, exact over the whole
+    int32 range; an index outside the table reads 0.  1,100 rows take
+    three one-hot chunks."""
+    from jax.experimental import pallas as pl
+    from repro.kernels.gather import pack_table, table_gather
+
+    rng = np.random.default_rng(rows)
+    size = rows * 128 - int(rng.integers(0, 128)) if rows > 1 else 100
+    a = rng.integers(-2**31, 2**31, size).astype(np.int32)
+    a[:5] = [INT_INF, -1, -2**31, 2**24, 2**24 + 1]
+    edges = [-1, -129, size, size + 127, 0, 1, 2, 3, 4, size - 1]
+    idx = np.concatenate([edges, rng.integers(0, size, 128 - len(edges))
+                          ]).astype(np.int32)
+
+    def kernel(tbl_ref, idx_ref, out_ref):
+        out_ref[...] = table_gather(tbl_ref, idx_ref[...])
+
+    got = np.asarray(pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((1, idx.size), jnp.int32),
+        interpret=True)(pack_table(a), jnp.asarray(idx)[None, :]))[0]
+    inside = (idx >= 0) & (idx < size)
+    want = np.where(inside, np.take(a, idx, mode="clip"), 0)
+    np.testing.assert_array_equal(got, want)
+
+
 # -- grammar_expand ---------------------------------------------------------------
 
 def test_grammar_expand_vs_ref_and_truth(lists):

@@ -253,6 +253,33 @@ def _run(served, trace_dir=None):
     return flights, before, after
 
 
+def test_mxu_lookup_counter_counts_each_launch(served):
+    """One interpret-mode launch of each kernel whose table lookups run on
+    the MXU raises its ``gather.mxu.<kernel>`` counter by one, beside
+    ``launch.<kernel>``; a kernel without table lookups raises none."""
+    from repro.core.jax_index import build_flat_index, build_score_index
+    from repro.kernels import MXU_PREFIX, count_launch
+    from repro.kernels.list_intersect.ops import next_geq
+
+    _, res, _ = served
+    eng = PallasEngine(res, max_short_len=64, interpret=True, page_size=128)
+    eng.set_score_index(build_score_index(res, page_size=128))
+    flat = build_flat_index(res)
+    one = np.zeros(1, np.int32)
+    launches = {
+        "list_intersect": lambda: next_geq(flat, one, one, interpret=True),
+        "page_score": lambda: eng.decode_page_batch(one),
+        "pair_count": lambda: count_launch("pair_count", True),
+    }
+    for kernel, launch in launches.items():
+        name = kernel + "[interpret]"
+        before = LAUNCHES[name], obs.counter(MXU_PREFIX + name)
+        launch()
+        mxu = kernel != "pair_count"
+        assert (LAUNCHES[name], obs.counter(MXU_PREFIX + name)) == (
+            before[0] + 1, before[1] + mxu), kernel
+
+
 def test_scheduler_spans_account_for_each_tick(served):
     flights, before, after = _run(served)
     ticks = delta(after, before, "sched.tick", "n")

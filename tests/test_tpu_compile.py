@@ -34,6 +34,8 @@ PAGES, PAGE = 213, 2048     # the paged compressed stream
 LANES = 65536               # probe lanes in one launch (512 tiles)
 K_PAGES = 4                 # pages one tile reads, when its lanes cluster
 STATICS = dict(max_scan=22, max_depth=15, T=2522)
+GOV2_LISTS = 61618          # gov2-web's lists (61,619 ``starts`` entries)
+GOV2_SYMBOLS = 29184        # and its symbol tables, 228 rows
 
 
 @pytest.fixture(scope="module")
@@ -78,19 +80,31 @@ def _assert_kernel(compiled) -> None:
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("k_pages", [K_PAGES, PAGES])
-def test_list_intersect_compiles(i32, k_pages):
-    """The largest launch the router makes: as many tiles as fit SMEM,
-    each reading a few pages or every page of the stream."""
-    tables = (_table(i32, LISTS + 1), _table(i32, LISTS),
-              _table(i32, RULE_TABLE), _table(i32, RULE_TABLE),
-              _table(i32, RULE_TABLE),
+def _compile_list_intersect(i32, k_pages: int, lists: int,
+                            symbols: int) -> None:
+    tables = (_table(i32, lists + 1), _table(i32, lists),
+              _table(i32, symbols), _table(i32, symbols),
+              _table(i32, symbols),
               i32(PAGES, 1, PAGE), i32(PAGES, 1, PAGE))
     tiles = LI.tiles_per_launch(k_pages)
     lanes = [i32(tiles * 128) for _ in range(4)]
     _assert_kernel(LI._paged_call.lower(
         tables, i32(tiles), i32(tiles, k_pages), *lanes,
         k_pages=k_pages, interpret=False, **STATICS).compile())
+
+
+@pytest.mark.parametrize("k_pages", [K_PAGES, PAGES])
+def test_list_intersect_compiles(i32, k_pages):
+    """The largest launch the router makes: as many tiles as fit SMEM,
+    each reading a few pages or every page of the stream."""
+    _compile_list_intersect(i32, k_pages, LISTS, RULE_TABLE)
+
+
+def test_list_intersect_compiles_at_gov2_tables(i32):
+    """The benchmark's largest tables (``gov2-web``): 482-row list
+    directories, whose lookups' byte planes and row one-hots must fit
+    the scoped VMEM beside 228-row symbol tables."""
+    _compile_list_intersect(i32, K_PAGES, GOV2_LISTS, GOV2_SYMBOLS)
 
 
 def _compile_page_score(i32, entries: int, b_pad: int = 1024) -> None:
