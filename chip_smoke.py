@@ -16,16 +16,22 @@ through the objects ``python -m repro.launch.serve --tier queries`` uses:
 5. BM25 top-k through ``search_topk_many`` at k=10 and k=100;
 6. the adaptive codec tier (plus a forced Elias-Fano tier when the cost
    model gives Elias-Fano no probe lanes);
-7. the pallas builder at 2,000 docs, bit-identical to the host builder.
+7. out of core: the benchmark's ``gov2-web-ooc`` shard
+   (``perfbench/configs/gov2-web-ooc.json``: the ``gov2-web`` collection,
+   its stream pages in a memory page store, a quarter of them resident)
+   served AND queries through the scheduler eight at a time;
+8. the pallas builder at 2,000 docs, bit-identical to the host builder.
 
 Every answer of every phase is compared with a plain reference
 (``np.intersect1d`` and ``HostEngine`` for pairs, ``naive_eval`` for
-boolean queries, ``rank_oracle`` for top-k).  The run fails when JAX finds
+boolean queries, ``rank_oracle`` for top-k, the intersection of the raw
+lists out of core).  The run fails when JAX finds
 no TPU, when any answer differs, when a pallas engine or builder would
 interpret its kernels, or when one of ``list_intersect``, ``page_score``,
 ``ef_next_geq`` and ``pair_count`` did not launch compiled, or when a
 launch of ``list_intersect`` or ``page_score`` did not count its table
-lookups on the MXU (``gather.mxu.<kernel>``).  With
+lookups on the MXU (``gather.mxu.<kernel>``), or when the out-of-core
+phase faulted no page or grew its pool (``store.*`` counters).  With
 ``--chips 4`` the run serves the boolean workload from
 ``QueryServer(mesh=...)`` over four chips and compares it with the
 one-chip engine and the oracle.  Each phase prints its wall time and
@@ -47,6 +53,11 @@ VOCAB = 4000          # PostingsSource vocabulary
 PAIRS = 256           # conjunctive pairs in the pairs phase
 CONCURRENCY = 64      # scheduler batch window of the boolean phases
 BUILD_DOCS = 2000     # the launcher's default corpus, pallas-builder phase
+OOC_CONFIG = "gov2-web-ooc"   # the out-of-core phase's benchmark shard
+OOC_MIX = "and-web-closed8"   # and its queries
+OOC_QUERIES = 400
+STORE_COUNTERS = ("store.lookups", "store.hits", "store.faults",
+                  "store.fault_bytes", "store.pool_grows")
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 CACHE_HIT = "/jax/compilation_cache/cache_hits"
 
@@ -192,6 +203,39 @@ def one_chip(args, meter, lists, res) -> None:
         require(n > 0, "no codec tier launched ef_next_geq")
         return "; ".join(notes)
 
+    def ooc_phase():
+        ooc = Path(__file__).resolve().parent / "perfbench"
+        sys.path.insert(0, str(ooc))
+        import corpus
+        import traffic
+        cfg = json.loads((ooc / "configs" / f"{OOC_CONFIG}.json").read_text())
+        mix = json.loads((ooc / "traffic" / f"{OOC_MIX}.json").read_text())
+        olists, _ = corpus.make_collection(cfg, int(cfg["collection_seed"]))
+        ores = make_builder("host").build_grammar(olists)
+        osrv = QueryServer(ores, engine="pallas", max_short_len=256,
+                           batch_window=int(mix["clients"]), **cfg["server"])
+        require(osrv.engine.interpret is False,
+                "pallas engine would interpret")
+        queries = traffic.QueryStream(mix, olists, args.seed, 0).take(
+            OOC_QUERIES)
+        before = {k: obs.counter(k) for k in STORE_COUNTERS}
+        outs = osrv.search_many([" AND ".join(map(str, q))
+                                 for q in queries])
+        for q, got in zip(queries, outs):
+            want = olists[q[0]]
+            for t in q[1:]:
+                want = np.intersect1d(want, olists[t])
+            np.testing.assert_array_equal(got, want, err_msg=str(q))
+        moved = {k: obs.counter(k) - before[k] for k in STORE_COUNTERS}
+        st = osrv.engine.resident.stats()
+        require(moved["store.faults"] > 0, "out of core: no page faulted")
+        require(moved["store.pool_grows"] == 0,
+                f"out of core: the pool grew to {st['budget']} pages")
+        return (f"{len(queries)} queries, {sum(map(len, outs))} hits, "
+                f"{st['num_pages']} pages, {st['budget']} resident, "
+                f"counters {moved}, evictions {st['page_evictions']}, "
+                f"host routes {osrv.serve_stats()['host_routes']}")
+
     def build_phase():
         small = build_collection(BUILD_DOCS, args.seed)
         host = make_builder("host", table_cap=4096).build_grammar(small)
@@ -216,6 +260,7 @@ def one_chip(args, meter, lists, res) -> None:
     run_phase(meter, "boolean", boolean_phase)
     run_phase(meter, "topk", topk_phase)
     run_phase(meter, "codec", codec_phase)
+    run_phase(meter, "out-of-core", ooc_phase)
     run_phase(meter, "build", build_phase)
 
     print(f"kernel launches: {dict(LAUNCHES)}", flush=True)

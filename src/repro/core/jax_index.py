@@ -220,17 +220,19 @@ class PagedIndex:
       ``bck_page * page_size + bck_off == starts[list] + bck_c_pos``).
 
     Like ``FlatIndex`` it is a registered pytree: arrays are leaves,
-    ``page_size`` is static aux data (``num_pages`` is just
-    ``c_syms_pg.shape[0]``).  The flat index travels along as a nested
-    pytree so paged consumers still see the grammar, spans, and static
-    bounds.
+    ``page_size`` and ``stored_pages`` are static aux data (``num_pages``
+    is just ``c_syms_pg.shape[0]``).  The flat index travels along as a
+    nested pytree so paged consumers still see the grammar, spans, and
+    static bounds.
 
-    Out-of-core (DESIGN.md §11): when ``store`` is set, the stream pages
-    live behind that :class:`repro.store.PageStore` and ``c_syms_pg`` /
-    ``c_sums_pg`` shrink to a ``(1, page_size)`` placeholder — consumers
-    must dispatch against a resident pool instead of these leaves, and
-    ``num_pages`` reports the store's geometry.  The directory/bucket
-    arrays stay real (they are the RAM tier, per the paper).
+    Out-of-core (DESIGN.md §11): when ``stored_pages`` is set, the stream
+    pages live behind the engine's :class:`repro.store.PageStore` and
+    ``c_syms_pg`` / ``c_sums_pg`` shrink to a ``(1, page_size)``
+    placeholder — consumers must dispatch against a resident pool instead
+    of these leaves, and ``num_pages`` reports the store's geometry.  The
+    store itself is not part of the index, so one trace serves every store
+    of a geometry.  The directory/bucket arrays stay real (they are the
+    RAM tier, per the paper).
     """
 
     flat: FlatIndex
@@ -241,15 +243,14 @@ class PagedIndex:
     bck_off: jax.Array      # per-bucket offset within the page
 
     page_size: int = dataclasses.field(metadata=dict(static=True))
-    #: Optional PageStore backing the stream (aux data: hashable by
-    #: identity; a new store means a new index generation anyway).
-    store: object = dataclasses.field(default=None,
-                                      metadata=dict(static=True))
+    #: Pages behind the engine's page store (0: the stream is on device).
+    stored_pages: int = dataclasses.field(default=0,
+                                          metadata=dict(static=True))
 
     @property
     def num_pages(self) -> int:
-        if self.store is not None:
-            return int(self.store.num_pages)
+        if self.stored_pages:
+            return self.stored_pages
         return int(self.c_syms_pg.shape[0])
 
 
@@ -260,20 +261,17 @@ def as_store_backed(pi: PagedIndex, store) -> PagedIndex:
     (and the out-of-core differential gate poisons the original arrays to
     prove nothing does)."""
     z = jnp.zeros((1, pi.page_size), jnp.int32)
-    return dataclasses.replace(pi, c_syms_pg=z, c_sums_pg=z, store=store)
+    return dataclasses.replace(pi, c_syms_pg=z, c_sums_pg=z,
+                               stored_pages=int(store.num_pages))
 
 
-def build_paged_index(fi: FlatIndex, page_size: int = DEFAULT_PAGE,
-                      store: "str | object | None" = None,
-                      store_dir: "str | None" = None) -> PagedIndex:
+def build_paged_index(fi: FlatIndex,
+                      page_size: int = DEFAULT_PAGE) -> PagedIndex:
     """Reshape a flat index's stream into ``(num_pages, page_size)`` pages
     and re-address the bucket tables as (page, offset).  Pure reshaping —
     values are untouched, so paged and flat consumers agree bit-exactly.
-
-    ``store`` (explicit only — the env axis is resolved by the engines)
-    additionally builds a page store from the freshly paged arrays and,
-    for disk-backed kinds, swaps the stream leaves for placeholders via
-    :func:`as_store_backed`."""
+    The engines move the stream behind a page store themselves
+    (:func:`as_store_backed`)."""
     page_size = max(128, -(-page_size // 128) * 128)  # lane multiple
     c = np.asarray(fi.c, dtype=np.int32)
     sums = np.asarray(fi.sym_sum, dtype=np.int32)[c]
@@ -290,7 +288,7 @@ def build_paged_index(fi: FlatIndex, page_size: int = DEFAULT_PAGE,
     owner = np.repeat(np.arange(starts.size - 1), np.diff(boffs))
     abs_pos = starts[owner] + bpos
 
-    pi = PagedIndex(
+    return PagedIndex(
         flat=fi,
         c_syms_pg=jnp.asarray(c_pg),
         c_sums_pg=jnp.asarray(s_pg),
@@ -299,16 +297,6 @@ def build_paged_index(fi: FlatIndex, page_size: int = DEFAULT_PAGE,
         bck_off=jnp.asarray((abs_pos % page_size).astype(np.int32)),
         page_size=page_size,
     )
-    if store is not None:
-        from ..store import PageStore, build_page_store
-        if not isinstance(store, PageStore):
-            store = build_page_store(None, kind=store, pi=pi,
-                                     store_dir=store_dir)
-        if store.kind != "memory":
-            pi = as_store_backed(pi, store)
-        else:
-            pi = dataclasses.replace(pi, store=store)
-    return pi
 
 
 # -- ranked scoring: BM25 tables + block-max page directory (DESIGN.md §9) ---

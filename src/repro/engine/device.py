@@ -231,9 +231,7 @@ class DeviceEngine(Engine):
             return pi
         if self.resident is None:
             from ..store import (PageStore, ResidentSet, build_page_store)
-            if pi.store is not None:
-                self.store = pi.store
-            elif isinstance(self._store_kind, PageStore):
+            if isinstance(self._store_kind, PageStore):
                 self.store = self._store_kind
             else:
                 self.store = build_page_store(self.res,

@@ -199,11 +199,12 @@ def probe_batch_paged(pi: PagedIndex, long_ids: jax.Array,
 def _pool_read(pool: jax.Array, slot_tab: jax.Array, PAGE: int,
                p: jax.Array) -> jax.Array:
     """Read absolute stream position ``p`` through the resident slot
-    table.  ``slot_tab`` (num_pages,) global page -> pool row (-1 absent,
+    table.  ``pool`` is ``(slots, 1, PAGE)``, the kernels' page layout;
+    ``slot_tab`` (num_pages,) global page -> pool row (-1 absent,
     clamped to 0: reachable only at masked positions)."""
     npg = slot_tab.shape[0]
     slot = slot_tab[jnp.minimum(p // PAGE, npg - 1)]
-    return pool[jnp.maximum(slot, 0), p % PAGE]
+    return pool[jnp.maximum(slot, 0), 0, p % PAGE]
 
 
 def _next_geq_one_resident(pi: PagedIndex, pool_syms: jax.Array,

@@ -229,8 +229,7 @@ def _launch_routed(tables, host, list_ids, xs, *, max_scan, max_depth, T,
             resident.ensure(probe_working_set(host, list_ids, xs))
             tile_slots = np.maximum(
                 resident.slot_of_page[tile_pages], 0).astype(np.int32)
-            csyms, csums, _ = resident.device_tables()
-            tables = tables[:5] + (paged_rows(csyms), paged_rows(csums))
+            tables = tables[:5] + resident.device_pool()
     # one launch per chunk of tiles, so the scalar-prefetched page table
     # fits the chip's SMEM; every chunk is enqueued before any is read
     step = tiles_per_launch(k_pages)

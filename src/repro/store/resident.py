@@ -31,9 +31,14 @@ from __future__ import annotations
 import os
 import weakref
 from collections import OrderedDict, deque
+from functools import partial
 
 import numpy as np
 
+import jax
+import jax.numpy as jnp
+
+from .. import obs
 from .base import PageStore
 
 #: Resident-page budget env knob; <= 0 or unset means "everything fits"
@@ -54,6 +59,28 @@ def resident_budget(resident_pages, num_pages: int) -> int:
     if rp <= 0:
         return max(1, int(num_pages))
     return max(1, min(rp, int(num_pages)))
+
+
+def update_bucket(n: int, cap: int) -> int:
+    """Entries one device update carries for a batch of ``n`` (``1 <= n
+    <= cap``): the next power of two, at most ``cap``.  Batches of every
+    size from 1 to ``cap`` thus need ``ceil(log2 cap) + 1`` programs."""
+    return min(1 << (int(n) - 1).bit_length(), int(cap))
+
+
+@partial(jax.jit, donate_argnums=(0, 1))
+def _write_pool(pool_syms, pool_sums, slots, syms, sums):
+    """Write page rows into the device pool (``(slots, 1, PAGE)``, the
+    kernels' layout) at ``slots``.  The pool's buffers are donated, so the
+    update happens in place: no fault batch copies the pool."""
+    return (pool_syms.at[slots, 0].set(syms),
+            pool_sums.at[slots, 0].set(sums))
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _write_entries(table, idx, vals):
+    """``table[idx] = vals`` on the device, in place (donated)."""
+    return table.at[idx].set(vals)
 
 
 class ResidentSet:
@@ -87,10 +114,12 @@ class ResidentSet:
         self.prefetch_useful = 0
         self.prefetch_bytes = 0
         self._prefetch_outstanding: set[int] = set()
-        # lazy device mirror: full upload once, then incremental scatters
-        self._dev = None
-        self._pending: list[tuple[np.ndarray, np.ndarray, np.ndarray]] = []
-        self._slots_dirty = True
+        # lazy device mirror: a full upload once, then in-place updates of
+        # the slots and slot-table entries written since the last upload
+        self._dev = None            # (syms, sums), (slots, 1, PAGE)
+        self._dev_slots = None      # slot_of_page, uploaded on first use
+        self._dirty_slots: set[int] = set()
+        self._dirty_pages: set[int] = set()
 
     # -- admission -------------------------------------------------------
 
@@ -113,39 +142,58 @@ class ResidentSet:
         n_hit = int(hit.sum())
         self.lookups += int(pages.size)
         self.hits += n_hit
+        obs.count("store.lookups", int(pages.size))
+        obs.count("store.hits", n_hit)
         self._window.extend([True] * n_hit +
                             [False] * (int(pages.size) - n_hit))
         missing = pages[~hit]
         if missing.size == 0:
             return
-        if pages.size > self.budget:
-            self._grow(int(pages.size))
-        alloc: list[int] = []
-        while len(alloc) < missing.size and self._free:
-            alloc.append(self._free.pop())
-        if len(alloc) < missing.size:
-            pinned = set(int(p) for p in pages)
-            for p in list(self._lru):            # oldest first
-                if len(alloc) >= missing.size:
-                    break
-                if p in pinned:
-                    continue
-                alloc.append(self._lru.pop(p))
-                self.slot_of_page[p] = -1
-                self.page_evictions += 1
-        # budget >= |pages| and every non-pinned LRU entry is evictable,
-        # so allocation always succeeds
-        new_slots = np.asarray(alloc, np.int64)
-        syms, sums = self.store.gather(missing)
+        with obs.span("store.fault", pages=int(missing.size)):
+            if pages.size > self.budget:
+                self._grow(int(pages.size))
+            alloc: list[int] = []
+            while len(alloc) < missing.size and self._free:
+                alloc.append(self._free.pop())
+            if len(alloc) < missing.size:
+                pinned = set(int(p) for p in pages)
+                for p in list(self._lru):            # oldest first
+                    if len(alloc) >= missing.size:
+                        break
+                    if p in pinned:
+                        continue
+                    alloc.append(self._evict(p))
+            # budget >= |pages| and every non-pinned LRU entry is
+            # evictable, so allocation always succeeds
+            syms, sums = self.store.gather(missing)
+            self._admit(missing, alloc, syms, sums)
+        nbytes = int(missing.size) * self.store.page_size * 8
+        self.page_faults += int(missing.size)
+        self.fault_bytes += nbytes
+        obs.count("store.faults", int(missing.size))
+        obs.count("store.fault_bytes", nbytes)
+
+    def _evict(self, page: int) -> int:
+        """Drop ``page`` from the pool; returns the slot it frees."""
+        slot = self._lru.pop(page)
+        self.slot_of_page[page] = -1
+        self._dirty_pages.add(page)
+        self.page_evictions += 1
+        return slot
+
+    def _admit(self, pages: np.ndarray, slots: list[int], syms: np.ndarray,
+               sums: np.ndarray) -> None:
+        """Write gathered rows into ``slots`` of the host pool and map
+        ``pages`` to them; the device mirror catches up on its next
+        upload."""
+        new_slots = np.asarray(slots, np.int64)
         self.pool_syms[new_slots] = syms
         self.pool_sums[new_slots] = sums
-        self.slot_of_page[missing] = new_slots.astype(np.int32)
-        for p, sl in zip(missing, new_slots):
-            self._lru[int(p)] = int(sl)
-        self.page_faults += int(missing.size)
-        self.fault_bytes += int(missing.size) * self.store.page_size * 8
-        self._pending.append((new_slots.copy(), syms, sums))
-        self._slots_dirty = True
+        self.slot_of_page[pages] = new_slots.astype(np.int32)
+        for p, sl in zip(pages.tolist(), slots):
+            self._lru[p] = sl
+        self._dirty_slots.update(slots)
+        self._dirty_pages.update(pages.tolist())
 
     def _grow(self, min_pages: int) -> None:
         """One tick needs more pages than the pool holds: grow to the next
@@ -164,9 +212,9 @@ class ResidentSet:
         self.pool_syms, self.pool_sums = syms, sums
         self.budget = new
         self.pool_grows += 1
-        self._dev = None            # pool shape changed: full re-upload
-        self._pending.clear()
-        self._slots_dirty = True
+        obs.count("store.pool_grows")
+        # the pool's shape and the update buckets changed: upload anew
+        self._dev = self._dev_slots = None
 
     # -- overlapped prefetch (DESIGN.md §13.3) ---------------------------
 
@@ -214,20 +262,11 @@ class ResidentSet:
             for p in list(self._lru):            # oldest first
                 if len(alloc) >= pages.size:
                     break
-                alloc.append(self._lru.pop(p))
-                self.slot_of_page[p] = -1
-                self.page_evictions += 1
-        new_slots = np.asarray(alloc, np.int64)
-        self.pool_syms[new_slots] = syms
-        self.pool_sums[new_slots] = sums
-        self.slot_of_page[pages] = new_slots.astype(np.int32)
-        for p, sl in zip(pages, new_slots):
-            self._lru[int(p)] = int(sl)
-            self._prefetch_outstanding.add(int(p))
+                alloc.append(self._evict(p))
+        self._admit(pages, alloc, syms, sums)
+        self._prefetch_outstanding.update(pages.tolist())
         self.prefetched_pages += int(pages.size)
         self.prefetch_bytes += int(pages.size) * self.store.page_size * 8
-        self._pending.append((new_slots.copy(), syms, sums))
-        self._slots_dirty = True
         return int(pages.size)
 
     # -- addressing ------------------------------------------------------
@@ -249,31 +288,77 @@ class ResidentSet:
         return (self.pool_syms[slots].reshape(-1)[a:b],
                 self.pool_sums[slots].reshape(-1)[a:b])
 
-    def device_tables(self):
-        """jnp mirror of ``(pool_syms, pool_sums, slot_of_page)``.  First
-        call uploads the pool; later calls apply the pending fault batches
-        as incremental ``.at[slots].set`` scatters (one per fault batch,
-        i.e. at most one per tick) plus a slot-table refresh."""
-        import jax.numpy as jnp
-        if self._dev is None:
-            self._dev = dict(syms=jnp.asarray(self.pool_syms),
-                             sums=jnp.asarray(self.pool_sums),
-                             slots=jnp.asarray(self.slot_of_page))
-            self._pending.clear()
-            self._slots_dirty = False
-        else:
-            if self._pending:
-                idx = jnp.asarray(np.concatenate(
-                    [p[0] for p in self._pending]))
-                sy = jnp.asarray(np.vstack([p[1] for p in self._pending]))
-                su = jnp.asarray(np.vstack([p[2] for p in self._pending]))
-                self._dev["syms"] = self._dev["syms"].at[idx].set(sy)
-                self._dev["sums"] = self._dev["sums"].at[idx].set(su)
-                self._pending.clear()
-            if self._slots_dirty:
-                self._dev["slots"] = jnp.asarray(self.slot_of_page)
-                self._slots_dirty = False
-        return self._dev["syms"], self._dev["sums"], self._dev["slots"]
+    def device_pool(self) -> tuple[jax.Array, jax.Array]:
+        """The pool's device copy ``(syms, sums)``, each ``(budget, 1,
+        PAGE)`` int32 (the kernels' page layout).  The first call uploads
+        the pool; later calls write the slots faulted or prefetched since,
+        in place, through one donated update per bucket of at most
+        ``budget`` slots (``update_bucket``; a pad entry repeats a slot
+        with its own rows).  The span ``store.upload`` covers the
+        dispatch."""
+        if self._dev is not None and not self._dirty_slots:
+            return self._dev
+        with obs.span("store.upload"):
+            if self._dev is None:
+                P = self.store.page_size
+                self._dev = (jnp.asarray(self.pool_syms.reshape(-1, 1, P)
+                                         .copy()),
+                             jnp.asarray(self.pool_sums.reshape(-1, 1, P)
+                                         .copy()))
+                self._dirty_slots.clear()
+                self._warm(self._update_pool)
+            else:
+                slots = np.fromiter(sorted(self._dirty_slots), np.int64)
+                self._dirty_slots.clear()
+                self._update_pool(slots)
+        return self._dev
+
+    def device_tables(self) -> tuple[jax.Array, jax.Array, jax.Array]:
+        """``device_pool()`` and the slot table ``slot_of_page`` on the
+        device, for the programs that map stream pages to slots there (the
+        jnp ``*_resident`` programs; the pallas router maps them on the
+        host).  The table is uploaded on first use; later calls write the
+        entries changed since, in place, in buckets as the pool's."""
+        syms, sums = self.device_pool()
+        if self._dev_slots is None or self._dirty_pages:
+            with obs.span("store.upload"):
+                if self._dev_slots is None:
+                    self._dev_slots = jnp.asarray(self.slot_of_page.copy())
+                    self._dirty_pages.clear()
+                    self._warm(self._update_slots)
+                else:
+                    pages = np.fromiter(sorted(self._dirty_pages), np.int64)
+                    self._dirty_pages.clear()
+                    for i in range(0, pages.size, self.budget):
+                        self._update_slots(pages[i:i + self.budget])
+        return syms, sums, self._dev_slots
+
+    def _update_pool(self, slots: np.ndarray) -> None:
+        """Dispatch one donated pool update carrying ``slots`` (at most
+        ``budget``), padded to its bucket."""
+        n = update_bucket(slots.size, self.budget)
+        idx = np.pad(slots, (0, n - slots.size), mode="edge")
+        self._dev = _write_pool(*self._dev,
+                                jnp.asarray(idx.astype(np.int32)),
+                                jnp.asarray(self.pool_syms[idx]),
+                                jnp.asarray(self.pool_sums[idx]))
+
+    def _update_slots(self, pages: np.ndarray) -> None:
+        """Dispatch one donated slot-table update carrying ``pages`` (at
+        most ``budget``), padded to its bucket."""
+        n = update_bucket(pages.size, self.budget)
+        idx = np.pad(pages, (0, n - pages.size), mode="edge")
+        self._dev_slots = _write_entries(self._dev_slots,
+                                         jnp.asarray(idx.astype(np.int32)),
+                                         jnp.asarray(self.slot_of_page[idx]))
+
+    def _warm(self, update) -> None:
+        """Run ``update`` once at every bucket size, each run rewriting
+        slot or page 0 with its own contents: the first pool of a shape
+        compiles every program here, so no later fault batch compiles."""
+        cap = self.budget
+        for n in [1 << i for i in range((cap - 1).bit_length())] + [cap]:
+            update(np.zeros(n, np.int64))
 
     def release(self) -> None:
         """Drop this pool's pin on the store explicitly (idempotent); a

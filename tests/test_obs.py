@@ -316,3 +316,34 @@ def test_scheduler_spans_are_host_events_in_a_profile(served, tmp_path):
                     ticks.update(v for k, v in ev.stats if k == "tick")
     assert {"sched.tick", *TICK_CHILDREN} <= names
     assert len(ticks) >= 2
+
+
+# -- the residency tier -------------------------------------------------------
+
+STORE_COUNTERS = {"store.lookups": "lookups", "store.hits": "hits",
+                  "store.faults": "page_faults",
+                  "store.fault_bytes": "fault_bytes",
+                  "store.pool_grows": "pool_grows"}
+
+
+@pytest.mark.parametrize("budget", [1, 2])
+def test_store_counters_equal_the_pools_own_fields(served, budget):
+    """After a window served out of core, the recorder's ``store.*``
+    counters have moved by exactly the pool's own fields (a fresh pool
+    starts from zero), and the spans ``store.fault`` and ``store.upload``
+    are in ``stats()["spans"]``."""
+    lists, res, queries = served
+    before = obs.snapshot()
+    eng = PallasEngine(res, max_short_len=64, interpret=True,
+                       page_size=128, store="memory", resident_pages=budget)
+    sch = QueryScheduler(eng, batch_window=4, result_cache_size=0)
+    for q, got in zip(queries, sch.search_many(queries)):
+        np.testing.assert_array_equal(got, naive_eval(q, lists,
+                                                      res.universe))
+    after = sch.stats()["spans"]
+    st = eng.resident.stats()
+    for counter, field in STORE_COUNTERS.items():
+        assert delta(after, before, counter) == st[field], counter
+    assert st["page_faults"] > 0 and st["lookups"] > st["hits"]
+    for name in ("store.fault", "store.upload"):
+        assert delta(after, before, name, "n") > 0, name

@@ -464,3 +464,155 @@ def test_inflight_query_across_swap_and_close(slists, sres):
     # the new index serves on untouched fresh state
     np.testing.assert_array_equal(
         srv.search(q), naive_eval(srv.plan(q).node, lists2, res2.universe))
+
+
+# -- the served path over a bounded pool, and its device copy -------------
+
+
+def _num_pages(res) -> int:
+    return -(-int(np.asarray(res.starts)[-1]) // PAGE)
+
+
+@pytest.mark.parametrize("share", ("one", "quarter", "all"))
+def test_served_and_queries_at_every_budget(share, slists, sres):
+    """``QueryServer`` on the pallas engine over a memory store, through
+    the scheduler with eight clients in flight: every AND answer is the
+    intersection of the raw lists, at a budget of one page, a quarter of
+    the pages and every page."""
+    pages = _num_pages(sres)
+    budget = {"one": 1, "quarter": -(-pages // 4), "all": pages}[share]
+    srv = QueryServer(sres, engine="pallas", interpret=True,
+                      max_short_len=600, page_size=PAGE, store="memory",
+                      resident_pages=budget, batch_window=8)
+    rng = np.random.default_rng(SEED + 61)
+    queries = [sorted(rng.choice(len(slists), int(rng.integers(2, 5)),
+                                 replace=False).tolist())
+               for _ in range(24)]
+    sch, todo, flight, got = srv.scheduler, list(queries), {}, {}
+    while todo or flight:
+        while todo and len(flight) < 8:     # a client sends its next query
+            q = todo.pop()
+            flight[srv.submit(" AND ".join(map(str, q)))] = q
+        sch.tick()
+        for qid in [i for i in flight if i in sch._done]:
+            got[tuple(flight.pop(qid))] = sch.take(qid)
+    for q in queries:
+        want = slists[q[0]]
+        for t in q[1:]:
+            want = np.intersect1d(want, slists[t])
+        np.testing.assert_array_equal(got[tuple(q)], want)
+    st = srv.engine.resident.stats()
+    assert st["page_faults"] > 0
+    assert not any(srv.engine.host_routes.values())
+    if share == "one":
+        assert st["page_evictions"] > 0
+    if share == "all":
+        assert st["page_evictions"] == 0
+
+
+class _Compiles:
+    """Executables JAX obtains while the block runs (``jax.monitoring``
+    backend compile events, as the benchmark's ``CompileMeter`` counts)."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self):
+        self.n = 0
+
+    def _on(self, event, duration, **_):
+        if event == self.EVENT:
+            self.n += 1
+
+    def __enter__(self):
+        import jax
+        jax.monitoring.register_event_duration_secs_listener(self._on)
+        return self
+
+    def __exit__(self, *exc):
+        import jax
+        jax.monitoring.unregister_event_duration_listener(self._on)
+        return False
+
+
+@pytest.mark.parametrize("budget", (1, 5, 8, 13))
+def test_pool_updates_compile_log_many_programs_and_donate(budget):
+    """Fault batches of every size from 1 to the budget, in a shuffled
+    order and twice over, obtain at most ceil(log2 budget) + 1 programs
+    for the pool's device updates and as many for the slot table's.
+    Each update donates the array it replaces (no batch copies the pool),
+    and the device copy equals the host pool and slot table after each."""
+    import math
+
+    # shapes of pool and slot table that no other test compiles
+    P, num_pages = 256 + 128 * budget, 997 + 4 * budget
+    rng = np.random.default_rng(SEED + 71 + budget)
+    syms = rng.integers(0, 1 << 20, (num_pages, P)).astype(np.int32)
+    sums = rng.integers(0, 1 << 20, (num_pages, P)).astype(np.int32)
+    store = MemoryPageStore(syms, sums, num_pages * P,
+                            {"starts": np.zeros(2, np.int64)})
+    rs = ResidentSet(store, budget=budget)
+    sizes = list(range(1, budget + 1)) * 2
+    rng.shuffle(sizes)
+    pool_progs = slot_progs = 0
+    for n in sizes:
+        absent = np.flatnonzero(rs.slot_of_page < 0)
+        rs.ensure(rng.choice(absent, n, replace=False))
+        old = rs._dev
+        with _Compiles() as c:
+            dev_syms, dev_sums = rs.device_pool()
+        pool_progs += c.n
+        if old is not None:
+            assert old[0].is_deleted() and old[1].is_deleted()
+        old_slots = rs._dev_slots
+        with _Compiles() as c:
+            _, _, dev_slots = rs.device_tables()
+        slot_progs += c.n
+        if old_slots is not None:
+            assert old_slots.is_deleted()
+        np.testing.assert_array_equal(np.asarray(dev_syms)[:, 0],
+                                      rs.pool_syms)
+        np.testing.assert_array_equal(np.asarray(dev_sums)[:, 0],
+                                      rs.pool_sums)
+        np.testing.assert_array_equal(np.asarray(dev_slots),
+                                      rs.slot_of_page)
+    bound = math.ceil(math.log2(budget)) + 1
+    assert 1 <= pool_progs <= bound and 1 <= slot_progs <= bound
+    assert rs.pool_grows == 0 and rs.page_faults == sum(sizes)
+
+
+def test_pool_update_buckets():
+    from repro.store.resident import update_bucket
+    assert [update_bucket(n, 38) for n in (1, 2, 3, 5, 17, 32, 33, 38)] \
+        == [1, 2, 4, 8, 32, 32, 38, 38]
+    assert {update_bucket(n, 38) for n in range(1, 39)} == \
+        {1, 2, 4, 8, 16, 32, 38}
+    assert update_bucket(1, 1) == 1
+
+
+@pytest.mark.parametrize("name", ("jnp_paged", "pallas"))
+def test_swap_onto_a_fresh_store_compiles_nothing(name, slists, sres):
+    """``swap_index`` builds a new store over the same pages.  A paged
+    index compares its store by kind and geometry in its pytree aux data,
+    so the swapped-in engine serves the same queries with the programs
+    already compiled, and gives the same answers."""
+    import jax
+    engine = dict(jnp_paged=dict(engine="jnp", paged=True),
+                  pallas=dict(engine="pallas", interpret=True))[name]
+    srv = QueryServer(sres, max_short_len=64, page_size=PAGE,
+                      store="memory",
+                      resident_pages=-(-_num_pages(sres) // 4), **engine)
+    qs = _bool_queries(np.random.default_rng(SEED + 83), len(slists))
+    first = srv.search_many(qs)
+    old = srv.engine
+    srv.swap_index(sres)
+    assert srv.engine.store is not old.store
+    assert (jax.tree.structure(srv.engine.pi)
+            == jax.tree.structure(old.pi))
+    with _Compiles() as c:
+        again = srv.search_many(qs)
+    assert c.n == 0
+    for q, a, b in zip(qs, first, again):
+        np.testing.assert_array_equal(a, b, err_msg=q)
+        np.testing.assert_array_equal(
+            b, naive_eval(srv.plan(q).node, slists, sres.universe))
+    assert srv.engine.resident.stats()["page_faults"] > 0

@@ -81,11 +81,12 @@ def _assert_kernel(compiled) -> None:
 
 
 def _compile_list_intersect(i32, k_pages: int, lists: int,
-                            symbols: int) -> None:
+                            symbols: int, rows: int = PAGES) -> None:
+    """``rows`` stream pages, or slots of a resident pool."""
     tables = (_table(i32, lists + 1), _table(i32, lists),
               _table(i32, symbols), _table(i32, symbols),
               _table(i32, symbols),
-              i32(PAGES, 1, PAGE), i32(PAGES, 1, PAGE))
+              i32(rows, 1, PAGE), i32(rows, 1, PAGE))
     tiles = LI.tiles_per_launch(k_pages)
     lanes = [i32(tiles * 128) for _ in range(4)]
     _assert_kernel(LI._paged_call.lower(
@@ -105,6 +106,31 @@ def test_list_intersect_compiles_at_gov2_tables(i32):
     directories, whose lookups' byte planes and row one-hots must fit
     the scoped VMEM beside 228-row symbol tables."""
     _compile_list_intersect(i32, K_PAGES, GOV2_LISTS, GOV2_SYMBOLS)
+
+
+#: slots of the resident pool: ``gov2-web-ooc``'s quarter of 151 pages,
+#: and a deployment's pool (4,096 pages of 2,048 symbols, 64 MiB a table)
+POOL_SLOTS = [38, 4096]
+
+
+@pytest.mark.parametrize("slots", POOL_SLOTS)
+def test_list_intersect_compiles_over_a_resident_pool(i32, slots):
+    """Out of core the kernel reads the bounded pool through the
+    scalar-prefetched slot table instead of the whole stream."""
+    _compile_list_intersect(i32, K_PAGES, GOV2_LISTS, GOV2_SYMBOLS,
+                            rows=slots)
+
+
+@pytest.mark.parametrize("slots", POOL_SLOTS)
+def test_pool_update_compiles_donated(i32, slots):
+    """The pool's device update at its largest bucket (every slot): it
+    compiles for the chip, and its outputs alias the donated pool."""
+    from repro.store.resident import _write_pool
+    pool = i32(slots, 1, PAGE)
+    rows = i32(slots, PAGE)
+    text = _write_pool.lower(pool, pool, i32(slots), rows, rows
+                             ).compile().as_text()
+    assert "input_output_alias" in text
 
 
 def _compile_page_score(i32, entries: int, b_pad: int = 1024) -> None:
