@@ -3,23 +3,20 @@
 
 A Zipf boolean/phrase workload (``common.boolean_workload``) is driven
 through the coalescing scheduler at concurrency {1, 8, 64} per engine
-backend, each cell twice: with cross-query lane dedup + the probe memo
-ON (the default serving configuration) and OFF (the PR 5 dispatch-every-
-lane path).  Concurrency 1 is the serial baseline (batch window 1 — one
-query in flight, coalescing factor exactly 1); higher windows let the
+backend, each cell twice: with cross-query lane dedup ON (the default
+serving configuration) and OFF (the dispatch-every-lane path).
+Concurrency 1 is the serial baseline (batch window 1 — one query in
+flight, coalescing factor exactly 1); higher windows let the
 scheduler merge the pending probe rounds of all in-flight queries into
 shared device dispatches.  Reported per cell: qps, p50/p95 latency, the
 mean coalescing factor, and the lane ledger — ``real_lanes`` (what the
 queries asked for), ``unique_lanes`` (what survived dedup),
 ``pad_lanes`` (pow2 filler; reported separately so no factor counts
-padding as work), plus ``dedup_factor`` and ``memo_hit_rate``.
+padding as work), plus ``dedup_factor``.
 
 Every result is oracle-checked on a warmup pass before timing, so a qps
-number can never come from a wrong answer.  The warmup also populates
-the probe memo in the ON cells — deliberately: the memo's steady state
-for hot Zipf terms is exactly what serving measures.  Honest-numbers
-note (same as BENCH_build): on a 2-core CPU box the host engine wins on
-raw qps — the device engines pay interpreter/XLA dispatch costs that
+number can never come from a wrong answer.  Honest-numbers note (same
+as BENCH_build): on a 2-core CPU box the host engine wins on raw qps — the device engines pay interpreter/XLA dispatch costs that
 batching amortizes but cannot erase; the coalescing factor and lane
 ledger are the hardware-portable signal (on a real accelerator each
 merged dispatch is one kernel launch, and every deduped lane is device
@@ -36,7 +33,6 @@ import time
 
 import numpy as np
 
-from repro.core.cache import LRUCache
 from repro.core.jax_index import build_flat_index
 from repro.core.repair import repair_compress
 from repro.engine import make_engine, validate_engines
@@ -64,12 +60,9 @@ def run(engines=DEFAULT_ENGINES, n_queries=64) -> list[dict]:
         kwargs = {"fi": fi} if name in ("jnp", "pallas") else {}
         for dedup_on in (True, False):
             eng = make_engine(name, res, **kwargs)
-            if not dedup_on:
-                eng.dedup = False
-                eng._probe_memo = LRUCache(0)
+            eng.dedup = dedup_on
             for conc in CONCURRENCY:
                 # warmup pass: jit compilation + the correctness gate
-                # (+ memo steady state in the ON cells)
                 warm = QueryScheduler(eng, batch_window=conc,
                                       result_cache_size=0)
                 for got, want in zip(warm.search_many(queries), oracle):
@@ -98,7 +91,6 @@ def run(engines=DEFAULT_ENGINES, n_queries=64) -> list[dict]:
                     "pad_lanes": st["pad_lanes"],
                     "dispatched_lanes": st["dispatched_lanes"],
                     "dedup_factor": st["dedup_factor"],
-                    "memo_hit_rate": st["memo_hit_rate"],
                 })
                 emit(rows[-1:], f"{name} × concurrency {conc} × "
                                 f"dedup={'on' if dedup_on else 'off'}")

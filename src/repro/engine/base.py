@@ -57,12 +57,6 @@ from ..core.repair import RePairResult
 #: ``REPRO_DECODE_CACHE``; 0 disables caching)
 DECODE_CACHE_SIZE = int(os.environ.get("REPRO_DECODE_CACHE", "512"))
 
-#: entry bound of the per-engine probe memo (DESIGN.md §13.2) — repeat
-#: ``(list, x)`` probes across ticks skip device dispatch entirely.
-#: Env override ``REPRO_PROBE_MEMO``; 0 disables memoization.
-PROBE_MEMO_SIZE = int(os.environ.get("REPRO_PROBE_MEMO", "4096"))
-
-
 def _env_flag(name: str, default: bool = True) -> bool:
     raw = os.environ.get(name, "").strip().lower()
     if not raw:
@@ -132,20 +126,10 @@ class Engine(abc.ABC):
         #: cross-query lane dedup toggle (DESIGN.md §13.1) — resolved
         #: from ``REPRO_DEDUP`` at construction; tests flip it per-engine
         self.dedup = DEDUP_ENABLED
-        #: bounded probe memo keyed ``(index_version, memo_epoch, algo,
-        #: list_id, x)`` (DESIGN.md §13.2).  The codec is implied by
-        #: ``list_id`` — one tier per engine, assignment fixed at build.
-        #: ``swap_index`` builds a FRESH engine per swap, so the memo is
-        #: structurally flushed on every hot swap; ``memo_epoch`` is the
-        #: fold point for any future tier that mutates list content under
-        #: one engine instance (today's segment engines are immutable).
-        self._probe_memo = LRUCache(PROBE_MEMO_SIZE)
-        self.memo_epoch = 0
         #: cumulative merged-round lane accounting (DESIGN.md §13.4);
         #: the scheduler snapshots deltas around each dispatch
         self.lane_stats = {"real_lanes": 0, "unique_lanes": 0,
-                           "pad_lanes": 0, "dispatched_lanes": 0,
-                           "memo_hits": 0, "memo_misses": 0}
+                           "pad_lanes": 0, "dispatched_lanes": 0}
         #: True while inside a merged-round dispatch — scopes the device
         #: engines' pad-lane accounting to the round path (point APIs
         #: like ``member_batch`` pad too but aren't merged-round work)
@@ -337,9 +321,7 @@ class Engine(abc.ABC):
         frontier — collapse to one representative via ``np.unique``'s
         inverse map before codec routing and padding; results scatter
         back to every requesting lane, bit-identical by construction.
-        Surviving unique lanes then consult the bounded probe memo; only
-        memo misses reach the device.  A round fully served by the memo
-        skips dispatch entirely."""
+        Nothing survives a round: every unique lane is dispatched."""
         with obs.span("engine.lanes"):
             lids = np.asarray(list_ids, np.int32).ravel()
             xq = np.asarray(xs, np.int32).ravel()
@@ -361,33 +343,12 @@ class Engine(abc.ABC):
                 else:
                     lids, xq = lids[uidx], xq[uidx]
             st["unique_lanes"] += lids.size
-            memo = self._probe_memo
-            if memo.maxsize > 0:
-                ver, ep = self.index_version, self.memo_epoch
-                out = np.empty(lids.size, np.int32)
-                lt, xt = lids.tolist(), xq.tolist()
-                miss = []
-                for j, (li, x) in enumerate(zip(lt, xt)):
-                    v = memo.get((ver, ep, algo, li, x))
-                    if v is None:
-                        miss.append(j)
-                    else:
-                        out[j] = v
-                st["memo_hits"] += lids.size - len(miss)
-                st["memo_misses"] += len(miss)
-                if miss:
-                    mi = np.asarray(miss, np.int64)
-                    vals = self._dispatch_lanes(lids[mi], xq[mi], algo)
-                    out[mi] = vals
-                    for j, v in zip(miss, vals.tolist()):
-                        memo.put((ver, ep, algo, lt[j], xt[j]), int(v))
-            else:
-                out = self._dispatch_lanes(lids, xq, algo)
+            out = self._dispatch_lanes(lids, xq, algo)
             return out if inv is None else out[inv]
 
     def _dispatch_lanes(self, lids: np.ndarray, xq: np.ndarray,
                         algo: str) -> np.ndarray:
-        """The post-dedup/post-memo slice of a merged round: codec
+        """The post-dedup slice of a merged round: codec
         routing + backend dispatch (the whole PR 5 round body)."""
         self.lane_stats["dispatched_lanes"] += lids.size
         self._in_round = True
@@ -531,8 +492,7 @@ class Engine(abc.ABC):
         Duplicate entry lanes — several ranked queries scoring the same
         hot page in one tick — dedup exactly like probe lanes: decode
         the unique set, scatter rows back via the inverse map
-        (DESIGN.md §13.1).  Page rows are too wide to memoize (the
-        decode LRU already caches at whole-list granularity)."""
+        (DESIGN.md §13.1)."""
         with obs.span("engine.lanes"):
             e = np.asarray(entries, np.int32).ravel()
             n = e.size

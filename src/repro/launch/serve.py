@@ -167,12 +167,11 @@ def serve_queries(n_queries: int, engine: str = "jnp",
               f"merged dispatches (window {st['batch_window']}), "
               f"spot checks OK")
         # hot-path dedup telemetry (DESIGN.md §13): real vs unique vs pad
-        # lanes, probe-memo reuse, and the prefetch overlap (zero unless
-        # an out-of-core store is attached)
+        # lanes, and the prefetch overlap (zero unless an out-of-core
+        # store is attached)
         print(f"hot-path dedup: factor {st['dedup_factor']:.2f} "
               f"({st['real_lanes']} real / {st['unique_lanes']} unique / "
-              f"{st['pad_lanes']} pad lanes), memo hit rate "
-              f"{st['memo_hit_rate']:.3f}, prefetch overlap "
+              f"{st['pad_lanes']} pad lanes), prefetch overlap "
               f"{st['overlap_ms']:.1f} ms "
               f"(accuracy {st['prefetch_accuracy']:.3f})")
         if st["store"] is not None:

@@ -84,7 +84,7 @@ PREFETCH_ENABLED = _env_flag("REPRO_PREFETCH", True)
 #: accumulates per-dispatch deltas so totals survive segment-engine
 #: churn and cover every engine a tick touches
 _LANE_KEYS = ("real_lanes", "unique_lanes", "pad_lanes",
-              "dispatched_lanes", "memo_hits", "memo_misses")
+              "dispatched_lanes")
 
 
 def _term_bag(q) -> list[int]:
@@ -663,7 +663,6 @@ class QueryScheduler:
         else:
             qps = 0.0
         lt = self._lane_totals
-        memo_total = lt["memo_hits"] + lt["memo_misses"]
         return {
             "completed": self._completed,
             "failures": self.failures,
@@ -678,20 +677,13 @@ class QueryScheduler:
             # are what queries asked for, unique lanes what survived
             # dedup, pad lanes the pow2 filler — reported separately so
             # no factor ever counts padding as work.  ``dedup_factor`` is
-            # real work per dispatched unique lane; ``memo_hit_rate`` the
-            # fraction of unique lanes served without touching a backend.
+            # real work per dispatched unique lane.
             "real_lanes": lt["real_lanes"],
             "unique_lanes": lt["unique_lanes"],
             "pad_lanes": lt["pad_lanes"],
             "dispatched_lanes": lt["dispatched_lanes"],
             "dedup_factor": (lt["real_lanes"] / lt["unique_lanes"]
                              if lt["unique_lanes"] else 0.0),
-            "memo_hits": lt["memo_hits"],
-            "memo_misses": lt["memo_misses"],
-            "memo_hit_rate": (lt["memo_hits"] / memo_total
-                              if memo_total else 0.0),
-            "probe_memo": getattr(self._engine, "_probe_memo",
-                                  LRUCache(0)).stats(),
             # overlapped prefetch (DESIGN.md §13.3)
             "prefetch_enabled": self.prefetch,
             "prefetched_pages": self.prefetched_pages,

@@ -1,19 +1,20 @@
 """Hot-path dedup differential gates (DESIGN.md §13).
 
-Cross-query lane dedup, the version-keyed probe memo, and overlapped
-page prefetch are all REQUIRED to be invisible in results: every
-(dedup, memo, prefetch) on/off combination must return bit-identical
-answers to the serial PR 5 path, on every engine configuration —
+Cross-query lane dedup and overlapped page prefetch are both REQUIRED
+to be invisible in results: every (dedup, prefetch) on/off combination
+must return bit-identical answers to the serial dispatch-every-lane
+path, on every engine configuration —
 host / jnp flat / jnp paged / pallas(interpret) / 1-device-mesh
 shard_map — across boolean, ranked top-k, mixed-codec, out-of-core
 (~10% resident budget) and segmented-ingest serving.
 
-Plus the behaviour pins: the probe memo flushes on ``swap_index``
-(structurally — a swap builds a fresh engine), insert-epoch correctness
-on the segmented tier, the prefetch thread is joined before its pages
-are touched (and never outlives a drained workload), and a crafted
-duplicate-heavy workload must show ``dedup_factor > 1`` with a SHRUNK
-pow2 dispatch bucket versus the dedup-off path.
+Plus the behaviour pins: ``swap_index`` serves the new index's
+answers, insert-epoch correctness on the segmented tier, no lane answer
+survives a tick (a replayed workload dispatches every unique lane
+again), the prefetch thread is joined before its pages are touched (and
+never outlives a drained workload), and a crafted duplicate-heavy
+workload must show ``dedup_factor > 1`` with a SHRUNK pow2 dispatch
+bucket versus the dedup-off path.
 """
 
 import os
@@ -60,19 +61,17 @@ def _make(name, res, **kw):
 
 
 def _off(eng):
-    """Disable every PR 10 optimization on an engine: the serial PR 5
-    dispatch path (dedup off, memo off)."""
+    """Disable lane dedup on an engine: the serial dispatch-every-lane
+    path."""
     eng.dedup = False
-    eng._probe_memo = LRUCache(0)
     return eng
 
 
 def _on(eng):
-    """Force dedup + memo ON regardless of the env knobs — the CI
-    `dedup-off`/`memo-tiny` cells run this whole file, so tests that
-    assert the optimizations ENGAGE must pin their own configuration."""
+    """Force dedup ON regardless of the env knob — the CI `dedup-off`
+    cell runs this whole file, so tests that assert dedup ENGAGES must
+    pin their own configuration."""
     eng.dedup = True
-    eng._probe_memo = LRUCache(4096)
     return eng
 
 
@@ -85,22 +84,14 @@ def _workload(num_lists, n, seed_off=0):
 
 @pytest.mark.parametrize("ename", ENGINE_CONFIGS)
 def test_dedup_memo_bit_identity(dlists, dres, ename):
-    """dedup-on ≡ memo-on ≡ all-off ≡ serial search ≡ oracle, per lane,
-    on every backend.  The workload repeats queries so dedup and the
-    memo both provably engage."""
+    """dedup-on ≡ dedup-off ≡ serial search ≡ oracle, per lane, on every
+    backend.  The workload repeats queries so dedup provably engages."""
     n = 8 if ename == "pallas" else 16
     queries = _workload(len(dlists), n) * 2          # repeats across ticks
     base = _off(_make(ename, dres))
     serial = [QueryExecutor(base).search(q) for q in queries]
-    combos = {"all-on": {}, "dedup-only": {"memo": 0},
-              "memo-only": {"dedup": False}, "all-off": {"memo": 0,
-                                                        "dedup": False}}
-    for label, knobs in combos.items():
-        eng = _on(_make(ename, dres))
-        if knobs.get("dedup") is False:
-            eng.dedup = False
-        if knobs.get("memo") == 0:
-            eng._probe_memo = LRUCache(0)
+    for label, setup in (("dedup-on", _on), ("dedup-off", _off)):
+        eng = setup(_make(ename, dres))
         sch = QueryScheduler(eng, batch_window=8, result_cache_size=0)
         for q, got, want in zip(queries, sch.search_many(queries), serial):
             np.testing.assert_array_equal(got, want,
@@ -111,7 +102,7 @@ def test_dedup_memo_bit_identity(dlists, dres, ename):
 
 
 def test_sharded_dispatch_bit_identity(dlists, dres):
-    """The deduped/memoized rounds ride the shard_map dispatch path."""
+    """The deduped rounds ride the shard_map dispatch path."""
     import jax
     from jax.sharding import Mesh
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
@@ -125,8 +116,8 @@ def test_sharded_dispatch_bit_identity(dlists, dres):
 
 
 def test_topk_bit_identity(dlists, dres):
-    """Ranked top-k: deduped ScoreRounds + memoized membership probes
-    return exactly the all-off docs AND scores."""
+    """Ranked top-k: deduped ScoreRounds and membership probes return
+    exactly the dedup-off docs AND scores."""
     rng = np.random.default_rng(SEED + 5)
     bags = [sorted(int(t) for t in rng.choice(8, 3, replace=False))
             for _ in range(10)] * 2
@@ -150,9 +141,9 @@ def test_topk_bit_identity(dlists, dres):
 
 
 def test_mixed_codec_bit_identity(dlists, dres):
-    """The dedup/memo layer sits ABOVE codec routing: adaptive-tier
-    engines with the optimizations on match the all-off tier exactly
-    (the memo key is version-scoped; codec is a function of list id)."""
+    """The dedup layer sits ABOVE codec routing: adaptive-tier engines
+    with dedup on match the oracle exactly (codec is a function of list
+    id, so a deduped lane keeps its codec)."""
     queries = _workload(len(dlists), 12, seed_off=7) * 2
     want = [naive_eval(q, dlists, dres.universe) for q in queries]
     for ename in ("host", "jnp"):
@@ -254,9 +245,9 @@ def test_prefetch_admission_never_grows_pool(dres):
 # -- segmented ingest + swap pins -------------------------------------------
 
 def test_segmented_ingest_bit_identity(dlists, dres):
-    """Interleaved insert/search with dedup+memo on matches the
-    rebuilt-from-scratch oracle after EVERY insert — the epoch pin: a
-    memoized probe can never leak a pre-insert answer (delta answers are
+    """Interleaved insert/search with dedup on matches the
+    rebuilt-from-scratch oracle after EVERY insert — the epoch pin: no
+    probe answer can leak from before an insert (delta answers are
     host-evaluated; segment engines are immutable)."""
     from repro.serve.query_serve import QueryServer
     vocab = 40
@@ -287,10 +278,9 @@ def test_segmented_ingest_bit_identity(dlists, dres):
     assert srv.serve_stats()["flushes"] >= 2
 
 
-def test_memo_flush_on_swap(dlists, dres):
-    """``swap_index`` leaves no stale memoized probe reachable: the swap
-    builds a FRESH engine (fresh memo), and the version token is folded
-    into every memo key besides."""
+def test_swap_index_serves_new_answers(dlists, dres):
+    """``swap_index`` leaves no old answer reachable: the swap builds a
+    FRESH engine, and the same query then answers from the new index."""
     from repro.serve.query_serve import QueryServer
     srv = QueryServer(dres, engine="host")
     _on(srv.engine)
@@ -299,12 +289,10 @@ def test_memo_flush_on_swap(dlists, dres):
     np.testing.assert_array_equal(srv.search(q, force_algo="svs"),
                                   want_old)
     old_engine = srv.engine
-    assert len(old_engine._probe_memo) > 0      # probes were memoized
     new_lists = [np.unique(l // 2) for l in dlists]
     new_res = repair_compress(new_lists)
     srv.swap_index(new_res)
     assert srv.engine is not old_engine
-    assert len(srv.engine._probe_memo) == 0     # structurally flushed
     want_new = naive_eval(q, new_lists, new_res.universe)
     np.testing.assert_array_equal(srv.search(q, force_algo="svs"),
                                   want_new)
@@ -340,37 +328,28 @@ def test_duplicate_heavy_dedup_factor_and_bucket(dlists, dres):
         (st_on, st_off)
 
 
-def test_memo_hits_across_ticks(dlists, dres):
-    """Steady state for hot terms: replaying a workload on the SAME
-    scheduler (result cache disabled) serves repeat probes from the
-    memo — fewer dispatched lanes, nonzero memo hit rate, same bits."""
+@pytest.mark.parametrize("ename", ("host", "jnp"))
+def test_repeat_workload_dispatches_every_unique_lane(dlists, dres, ename):
+    """No lane answer survives a tick: replaying a workload on the SAME
+    scheduler (result cache off) gives the same answers and sends every
+    unique lane to the backend again, exactly as the first pass did."""
     queries = _workload(len(dlists), 10, seed_off=4)
-    eng = _on(_make("host", dres))
-    sch = QueryScheduler(eng, batch_window=8, result_cache_size=0)
-    first = sch.search_many(queries, "svs")
-    d1 = sch.stats()["dispatched_lanes"]
-    second = sch.search_many(queries, "svs")
-    d2 = sch.stats()["dispatched_lanes"] - d1
-    for a, b in zip(first, second):
-        np.testing.assert_array_equal(a, b)
-    st = sch.stats()
-    assert st["memo_hit_rate"] > 0.0, st
-    assert d2 < d1, (d1, d2)
-    assert st["probe_memo"]["hits"] > 0
-
-
-def test_probe_memo_tiny_evicts(dlists, dres):
-    """A 4-entry memo churns (evictions > 0) yet stays exact — the
-    CI memo-tiny cell's focused pin."""
-    queries = _workload(len(dlists), 12, seed_off=6) * 2
-    eng = _make("host", dres)
-    eng._probe_memo = LRUCache(4)
-    sch = QueryScheduler(eng, batch_window=8, result_cache_size=0)
-    for q, got in zip(queries, sch.search_many(queries, "svs")):
-        np.testing.assert_array_equal(
-            got, naive_eval(q, dlists, dres.universe))
-    assert eng._probe_memo.stats()["evictions"] > 0
-    assert eng._probe_memo.stats()["size"] <= 4
+    want = [naive_eval(q, dlists, dres.universe) for q in queries]
+    sch = QueryScheduler(_on(_make(ename, dres)), batch_window=8,
+                         result_cache_size=0)
+    passes = []
+    for _ in range(2):
+        before = sch.stats()
+        outs = sch.search_many(queries, "svs")
+        after = sch.stats()
+        passes.append({k: after[k] - before[k]
+                       for k in ("unique_lanes", "dispatched_lanes")})
+        for got, w in zip(outs, want):
+            np.testing.assert_array_equal(got, w)
+    assert passes[0]["dispatched_lanes"] > 0
+    for p in passes:
+        assert p["dispatched_lanes"] == p["unique_lanes"], p
+    assert passes[0] == passes[1], passes
 
 
 # -- cache counter satellite -------------------------------------------------

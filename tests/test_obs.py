@@ -228,7 +228,6 @@ def _run(served, trace_dir=None):
     """The workload through a fresh scheduler; returns the answers, the
     queries' in-flight records and the recorder's snapshots around it."""
     lists, res, queries = served
-    # a fresh engine: its probe memo holds no answer yet
     eng = PallasEngine(res, max_short_len=64, interpret=True)
     sch = QueryScheduler(eng, batch_window=4, result_cache_size=0)
     gc.disable()            # no collection inside the spans compared
